@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import traceback
 
 
@@ -17,10 +18,14 @@ def _section(name):
     print(f"# --- {name} ---")
 
 
-def main() -> None:
+def main() -> int:
+    """Run every harness; returns the number that raised."""
     from benchmarks import (fig4_transport, fig5_breakdown, fig6_multiqp,
                             fig7_aes, fig8_dpi, fig10_dlrm, fig11_allreduce,
                             table2_resources)
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    failed = 0
     print("name,us_per_call,derived")
     for mod in (fig4_transport, fig5_breakdown, fig6_multiqp, fig7_aes,
                 fig8_dpi, table2_resources, fig10_dlrm, fig11_allreduce):
@@ -28,6 +33,7 @@ def main() -> None:
         try:
             mod.main()
         except Exception as e:           # keep the suite running
+            failed += 1
             print(f"{mod.__name__},nan,ERROR:{type(e).__name__}:{e}")
             traceback.print_exc()
 
@@ -48,7 +54,8 @@ def main() -> None:
                 n_skip += s == "skip"
                 n_fail += s == "FAIL"
         print(f"roofline_cells,0.0,ok={n_ok};skip={n_skip};fail={n_fail}")
+    return failed
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(1 if main() else 0)

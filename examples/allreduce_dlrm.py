@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.flatten_util import ravel_pytree
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.dlrm import smoke_config
 from repro.core.collectives import allreduce_oracle, make_ring_group
 from repro.data import synthetic as syn
@@ -43,6 +44,7 @@ def worker_batch(cfg, shard_idx):
 
 
 def main():
+    enable_compile_cache()
     cfg = smoke_config()
     model = DLRM(cfg)
     params = model.init_params(jax.random.key(0))

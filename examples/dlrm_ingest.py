@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.dlrm import smoke_config
 from repro.core.ingest import (BalboaIngest, IngestConfig,
                                make_dlrm_tile_decoder)
@@ -21,6 +22,7 @@ from repro.models.dlrm import DLRM
 
 
 def main():
+    enable_compile_cache()
     cfg = smoke_config()
     rec_w = cfg.n_dense + cfg.n_sparse
     recs_per_pkt = (4096 // 4) // rec_w

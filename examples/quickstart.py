@@ -5,6 +5,7 @@ API — config registry, Model, Trainer (sharded, checkpointed, resumable).
 """
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.common.config import TrainConfig
 from repro.configs import get_smoke_config
 from repro.launch.mesh import make_host_mesh
@@ -13,6 +14,7 @@ from repro.train.loop import Trainer, lm_batch_iterator
 
 
 def main():
+    enable_compile_cache()
     # 1. pick an architecture from the registry (reduced config: this
     #    container; the same ModelConfig at full size drives the
     #    multi-pod dry-run)

@@ -5,9 +5,13 @@ wire traffic into a PCAP you can open in Wireshark.
 
   PYTHONPATH=src python examples/secure_flow.py
 """
+import os
+import tempfile
+
 import numpy as np
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.netsim import LinkConfig, Network
 from repro.core.rdma import RdmaNode, run_network
 from repro.core.services import AesService, DpiService, ServiceChain
@@ -19,6 +23,7 @@ KEY = np.arange(16, dtype=np.uint8)
 
 
 def main():
+    enable_compile_cache()
     # train the DPI model (paper: CSV/PNG/TXT vs executables)
     x, y = make_dataset(2048, seed=0)
     dpi_params = train_dpi_params(x, y, steps=200)
@@ -53,8 +58,9 @@ def main():
         assert ok
     assert b.stats.dpi_flagged > 0, "DPI missed the malicious flow"
 
-    n = sniffer.write_pcap("/tmp/balboa_flow.pcap")
-    print(f"[secure] wrote {n} packets to /tmp/balboa_flow.pcap "
+    pcap = os.path.join(tempfile.gettempdir(), "balboa_flow.pcap")
+    n = sniffer.write_pcap(pcap)
+    print(f"[secure] wrote {n} packets to {pcap} "
           f"(RoCE v2 BTH frames; wire payloads are AES ciphertext)")
     print("secure_flow OK")
 

@@ -4,12 +4,14 @@ on SSM layers), greedy sampling.
 
   PYTHONPATH=src python examples/serve.py
 """
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_smoke_config
 from repro.launch.serve import serve_batch
 from repro.models.model import Model
 
 
 def main():
+    enable_compile_cache()
     for arch in ("gemma2-2b", "xlstm-125m", "recurrentgemma-9b"):
         cfg = get_smoke_config(arch)
         model = Model(cfg)

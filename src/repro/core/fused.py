@@ -70,7 +70,7 @@ _LAST_OPS = (pk.WRITE_LAST, pk.WRITE_ONLY,
 
 _PC_BUCKETS = (8, 16, 32, 64, 128, 256, 512)
 _CC_BUCKETS = (4, 8, 16, 32, 64, 128)
-_W_BUCKETS = (64, 128, 256, 512, 1024)
+_W_BUCKETS = (64, 128, 256, 512, 1024, 2048)
 
 
 def _bucket(n: int, opts) -> Optional[int]:
@@ -1425,7 +1425,8 @@ def run_fused_epoch(nodes, max_ticks: int = 100_000, idle_done: int = 8,
     Returns None when the world is not fusable or the in-graph twin hit
     a case it does not model (retry exhaustion, rkey protection error,
     wire-capacity overflow) — in that case the Python objects are
-    untouched and the caller falls back to per-tick stepping.
+    untouched and the caller falls back to per-tick stepping.  Either
+    outcome is counted in the network's ``epochs`` (``EpochStats``).
 
     On success the Python world has advanced exactly as ``for _ in
     range(steps): rdma.step_network(nodes)`` would have, and the return
@@ -1433,16 +1434,21 @@ def run_fused_epoch(nodes, max_ticks: int = 100_000, idle_done: int = 8,
     ``rdma.run_network`` return-value convention).
     """
     world = try_pack(nodes, max_ticks, idle_done, watermarks)
-    if world is None:
+    out = None
+    if world is not None:
+        out = np.asarray(make_epoch_fn(world.skey)(jnp.asarray(world.vec0)))
+        if world.layout.get(out, "abort"):
+            out = None
+    if out is None:
+        if nodes:
+            nodes[0].net.epochs.unfused += 1
         return None
-    fn = make_epoch_fn(world.skey)
-    out = np.asarray(fn(jnp.asarray(world.vec0)))
     lay = world.layout
-    if lay.get(out, "abort"):
-        return None
     steps = lay.get(out, "steps")
     idle_exit = lay.get(out, "idle") >= idle_done
     _apply(world, out, nodes)
+    world.net.epochs.fused += 1
+    world.net.epochs.fused_ticks += steps
     return {"steps": steps, "wm_hit": bool(lay.get(out, "wm_hit")),
             "idle_exit": idle_exit,
             "ticks": (steps - 1) if idle_exit else max_ticks}

@@ -194,11 +194,8 @@ def _place_tile_impl(buf: jax.Array, tile: jax.Array,
     return jax.lax.dynamic_update_slice(buf, tile, idx)
 
 
-# the stale landing buffer is donated so accelerator backends update it
-# in place; donation is unimplemented on CPU (would only warn)
-_place_tile = jax.jit(
-    _place_tile_impl,
-    donate_argnums=() if jax.default_backend() == "cpu" else (0,))
+# the stale landing buffer is donated so the update is in place
+_place_tile = jax.jit(_place_tile_impl, donate_argnums=(0,))
 
 
 class DeviceLandingZone:
@@ -208,9 +205,8 @@ class DeviceLandingZone:
     each completed tile is placed with a jitted ``dynamic_update_slice``
     whose tile shapes are fixed, so mid-stream placement never
     recompiles and never bounces through a host array.  The stale buffer
-    is DONATED, so on accelerator backends the update is genuinely in
-    place (XLA aliases output to input); the CPU backend cannot alias
-    and pays one buffer copy per placement instead."""
+    is DONATED, so the update is genuinely in place (XLA aliases output
+    to input)."""
 
     def __init__(self, specs: Dict[str, Tuple[Tuple[int, ...], np.dtype]],
                  shardings: Optional[Dict] = None):

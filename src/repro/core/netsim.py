@@ -62,6 +62,21 @@ from repro.core import packet as pk
 
 
 @dataclasses.dataclass
+class EpochStats:
+    """How ``rdma.run_network`` and the streaming ingest advanced this
+    network under ``epoch_mode="fused"``: whole epochs run by the fused
+    core, and attempts the core refused (each followed by one per-tick
+    oracle step).  A run that claims the fused core shows ``unfused ==
+    0``."""
+    fused: int = 0               # epochs run inside the jitted loop
+    fused_ticks: int = 0         # ticks those epochs covered
+    unfused: int = 0             # refused attempts -> one per-tick step
+
+    def snapshot(self) -> dict:
+        return dataclasses.asdict(self)
+
+
+@dataclasses.dataclass
 class LinkConfig:
     loss_prob: float = 0.0
     reorder_prob: float = 0.0
@@ -166,6 +181,7 @@ class Network:
                     self.links[(a, b)] = Link(c)
         self.now = 0
         self.recorder = None
+        self.epochs = EpochStats()
 
     def send(self, src: int, dst: int, p: pk.Packet):
         self.links[(src, dst)].send(p, self.now)
@@ -197,7 +213,8 @@ class Network:
         return {"now": self.now,
                 "injected": sum(l.sent for l in self.links.values()),
                 "wire_dropped": sum(l.dropped for l in self.links.values()),
-                "in_flight": sum(l.in_flight for l in self.links.values())}
+                "in_flight": sum(l.in_flight for l in self.links.values()),
+                "epochs": self.epochs.snapshot()}
 
 
 # ---------------------------------------------------------------------------
@@ -603,6 +620,7 @@ class SwitchedFabric:
                          self.port_stats[i]) for i in range(n_nodes)]
         self.reducer: Optional[SwitchReducer] = None
         self.recorder = None
+        self.epochs = EpochStats()
         self.injected = 0        # send() calls (conservation anchor)
         self._ctick = -1         # chaos mode: per-tick send / pop ranks
         self._csend = 0
@@ -715,6 +733,7 @@ class SwitchedFabric:
         + delivered + in_flight`` (absent a reducer, which consumes
         contributions and synthesizes new packets at the hop)."""
         snap = {"now": self.now, "injected": self.injected,
+                "epochs": self.epochs.snapshot(),
                 "in_flight": (len(self._wire)
                               + sum(len(q) for q in self.egress)),
                 **sum_port_stats(self.port_stats),
@@ -859,6 +878,7 @@ class ClosFabric:
         self.rerouted = 0                       # stamped path dead, re-picked
         self.injected = 0                       # send() calls
         self.recorder = None
+        self.epochs = EpochStats()
 
     # ---- topology helpers ---------------------------------------------
     def leaf_of(self, node: int) -> int:
@@ -1021,6 +1041,7 @@ class ClosFabric:
         up_flat = [s for row in self.uplink_stats for s in row]
         sp_flat = [s for row in self.spine_stats for s in row]
         return {"now": self.now, "injected": self.injected,
+                "epochs": self.epochs.snapshot(),
                 "failure_dropped": self.failure_dropped,
                 "rerouted": self.rerouted,
                 "alive_spines": len(self._alive),

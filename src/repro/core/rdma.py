@@ -435,14 +435,15 @@ class RdmaNode:
         self._emit_cnps(ecn_cnt, ce_path)
 
         # ---- service chain over the accepted payload stream -------------
+        # (services are row-independent: the chain runs on the padded
+        # batch, so it compiles once per pad size, not per packet count)
         payload = batch_np["payload"][:n]
-        plen = batch_np["plen"][:n]
         flags = np.zeros(n, np.int64)
         if self.services is not None:
-            out, f = self.services.process(jnp.asarray(payload),
-                                           jnp.asarray(plen))
-            payload = np.asarray(out)
-            flags = np.asarray(f)
+            out, f = self.services.process(jnp.asarray(batch_np["payload"]),
+                                           jnp.asarray(batch_np["plen"]))
+            payload = np.asarray(out)[:n]
+            flags = np.asarray(f)[:n]
 
         # ---- DMA accepted payloads into registered memory ----------------
         for i, p in enumerate(data_pkts):
@@ -818,7 +819,8 @@ def run_network(nodes: List[RdmaNode], max_ticks: int = 100_000,
     (``repro.core.fused``) instead of round-tripping device<->host every
     tick; any world the fused twin does not model falls back to per-tick
     stepping, one tick at a time, re-attempting fusion after each (e.g.
-    an in-flight READ_REQUEST unfuses only until it is ACKed).  The
+    an in-flight READ_REQUEST unfuses only until it is ACKed), and each
+    such tick is counted in ``net.epochs.unfused``.  The
     fused path is bit-identical to per-tick stepping — pinned by
     ``tests/test_fused_core.py`` — except that interleaving fallback
     ticks with fused epochs may re-run up to ``idle_done`` quiescent

@@ -27,7 +27,10 @@ import numpy as np
 
 
 class OnPathService:
-    """Payload transformer: (N, MTU) uint8 -> (N, MTU) uint8."""
+    """Payload transformer: (N, MTU) uint8 -> (N, MTU) uint8.  Row
+    independent: row i of the output depends on row i of the input
+    only (the RX path runs the chain on a zero-padded batch and keeps
+    the first N rows)."""
     name = "identity"
 
     def __call__(self, payload: jax.Array, plen: jax.Array) -> jax.Array:
@@ -35,7 +38,8 @@ class OnPathService:
 
 
 class ParallelPathService:
-    """Payload inspector: (N, MTU) uint8 -> (N,) int32 flags."""
+    """Payload inspector: (N, MTU) uint8 -> (N,) int32 flags.  Row
+    independent, as ``OnPathService``."""
     name = "null-inspect"
 
     def __call__(self, payload: jax.Array, plen: jax.Array) -> jax.Array:
@@ -151,7 +155,12 @@ class ServiceChain:
     stream as it arrives (before on-path transforms — e.g. ICRC over the
     wire bytes); ``parallel_after`` inspectors tap it after the on-path
     services (e.g. DPI over the *decrypted* payload of an encrypted
-    flow)."""
+    flow).
+
+    Every service must be row independent: ``RdmaNode`` hands the chain
+    its RX batch padded with zero rows to a power-of-two multiple of
+    ``rdma.RX_PAD`` (so the chain compiles once per padded size, not
+    once per packet count) and keeps the first N rows of the result."""
 
     MAX_INSPECTORS = 32          # decision flags pack into one 32-bit word
 

@@ -15,38 +15,39 @@ detection quality is benchmarked in benchmarks/fig8_dpi.py.
 from __future__ import annotations
 
 import functools
-from typing import Dict
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
 from repro.kernels import ref as R
 
 BLOCK_B = 512           # beats per tile
-INTERPRET = jax.default_backend() == "cpu"
 D_IN, D_H1, D_H2 = R.DPI_DIMS
 
 
 def _dpi_kernel(beats_ref, w1_ref, b1_ref, w2_ref, b2_ref, w3_ref,
                 scales_ref, out_ref):
+    # full f32 contractions: at default precision the MXU rounds operands
+    # to bfloat16 and the scores leave the reference by ~3e-2 on a v5e
+    dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32,
+                            precision=jax.lax.Precision.HIGHEST)
     x = beats_ref[...].astype(jnp.float32) / 128.0 - 1.0     # (BB, 64)
     s = scales_ref[...]                                      # (1, 3)
     h = jnp.maximum(
-        jnp.dot(x, w1_ref[...].astype(jnp.float32) * s[0, 0],
-                preferred_element_type=jnp.float32) + b1_ref[...], 0.0)
+        dot(x, w1_ref[...].astype(jnp.float32) * s[0, 0]) + b1_ref[...], 0.0)
     h = jnp.maximum(
-        jnp.dot(h, w2_ref[...].astype(jnp.float32) * s[0, 1],
-                preferred_element_type=jnp.float32) + b2_ref[...], 0.0)
-    y = jnp.dot(h, w3_ref[...].astype(jnp.float32) * s[0, 2],
-                preferred_element_type=jnp.float32)
+        dot(h, w2_ref[...].astype(jnp.float32) * s[0, 1]) + b2_ref[...], 0.0)
+    y = dot(h, w3_ref[...].astype(jnp.float32) * s[0, 2])
     out_ref[...] = y
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def dpi_scores_pallas(payload: jax.Array, params: Dict, *,
-                      interpret: bool = INTERPRET) -> jax.Array:
+                      interpret: Optional[bool] = None) -> jax.Array:
     """payload (N, MTU) uint8 -> per-beat scores (N, MTU//64) float32."""
     n, mtu = payload.shape
     beats = mtu // 64
@@ -70,7 +71,7 @@ def dpi_scores_pallas(payload: jax.Array, params: Dict, *,
         ],
         out_specs=pl.BlockSpec((BLOCK_B, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m + pad, 1), jnp.float32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, params["w1"].astype(jnp.int32), params["b1"],
       params["w2"].astype(jnp.int32), params["b2"],
       params["w3"].astype(jnp.int32), scales)

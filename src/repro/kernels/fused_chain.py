@@ -5,25 +5,26 @@ core, ...), each with its own stream pass.  On TPU the equivalent chain
 costs one HBM round trip *per service*; this kernel fuses
 AES-ECB-decrypt + ML-DPI scoring into a single VMEM-resident pass —
 payload bytes are read from HBM exactly once, decrypted in registers,
-scored, and written once.  2x HBM-traffic reduction over the two-stage
-chain for the receiver hot path (measured in benchmarks/fig8_dpi.py's
-fused variant; see EXPERIMENTS.md §Perf).
+scored, and written once: by construction half the HBM traffic of the
+two-stage chain on the receiver hot path.  No module on the data path
+calls it, and the TPU compiler refuses it (in-kernel gathers and
+reshapes): it runs in interpret mode only, off the chip path.
 """
 from __future__ import annotations
 
 import functools
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
 from repro.kernels import ref as R
 from repro.kernels.ref import DPI_DIMS
 
 BLOCK_N = 16            # packets per tile (x 4096 B = 256 KiB VMEM tile)
-INTERPRET = jax.default_backend() == "cpu"
 D_IN, D_H1, D_H2 = DPI_DIMS
 
 
@@ -66,7 +67,7 @@ def _fused_kernel(pay_ref, rk_ref, sbox_ref, sidx_ref, w1_ref, b1_ref,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def fused_decrypt_dpi_pallas(payload: jax.Array, round_keys,
                              dpi_params: Dict, *,
-                             interpret: bool = INTERPRET
+                             interpret: Optional[bool] = None
                              ) -> Tuple[jax.Array, jax.Array]:
     """payload (N, MTU) uint8 -> (plaintext (N, MTU) uint8, max-beat
     DPI score (N,) float32) in ONE pass."""
@@ -101,7 +102,7 @@ def fused_decrypt_dpi_pallas(payload: jax.Array, round_keys,
             jax.ShapeDtypeStruct((n + pad, mtu), jnp.int32),
             jax.ShapeDtypeStruct((n + pad, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, rk, inv_sbox, iidx,
       dpi_params["w1"].astype(jnp.int32), dpi_params["b1"],
       dpi_params["w2"].astype(jnp.int32), dpi_params["b2"],
@@ -111,7 +112,7 @@ def fused_decrypt_dpi_pallas(payload: jax.Array, round_keys,
 
 def fused_decrypt_dpi_tile(payload: jax.Array, round_keys,
                            dpi_params: Dict, *, tile_pkts: int = BLOCK_N,
-                           interpret: bool = INTERPRET
+                           interpret: Optional[bool] = None
                            ) -> Tuple[jax.Array, jax.Array]:
     """Tile-granular streaming entry: run the fused decrypt+DPI pass over
     one fragment tile of at most ``tile_pkts`` packets as it arrives.

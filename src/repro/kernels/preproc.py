@@ -13,15 +13,16 @@ read, one write, zero intermediate traffic (vs. three separate ops).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
 from repro.kernels import ref as R
 
 BLOCK_M = 512
-INTERPRET = jax.default_backend() == "cpu"
 
 
 def _preproc_kernel(recs_ref, out_ref, *, n_dense: int, modulus: int):
@@ -37,7 +38,7 @@ def _preproc_kernel(recs_ref, out_ref, *, n_dense: int, modulus: int):
 @functools.partial(jax.jit, static_argnames=("n_dense", "modulus",
                                              "interpret"))
 def preproc_pallas(recs: jax.Array, n_dense: int, modulus: int, *,
-                   interpret: bool = INTERPRET) -> jax.Array:
+                   interpret: Optional[bool] = None) -> jax.Array:
     """recs (M, RW) int32 -> (M, RW) int32 (dense part = f32 bits)."""
     m, rw = recs.shape
     pad = (-m) % BLOCK_M
@@ -48,14 +49,14 @@ def preproc_pallas(recs: jax.Array, n_dense: int, modulus: int, *,
         in_specs=[pl.BlockSpec((BLOCK_M, rw), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((BLOCK_M, rw), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m + pad, rw), jnp.int32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x)
     return out[:m]
 
 
 def preproc_tile(recs: jax.Array, n_dense: int, modulus: int, *,
                  tile_recs: int = BLOCK_M,
-                 interpret: bool = INTERPRET) -> jax.Array:
+                 interpret: Optional[bool] = None) -> jax.Array:
     """Tile-granular streaming entry: preprocess one fragment tile of at
     most ``tile_recs`` records the moment its bytes are acknowledged.
 

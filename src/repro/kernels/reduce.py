@@ -24,13 +24,15 @@ like the preprocessing service handles record words.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
+
 BLOCK_L = 512           # elements per grid tile (f32: 2 KB VMEM per row)
-INTERPRET = jax.default_backend() == "cpu"
 
 DTYPES = {"float32": jnp.float32, "int32": jnp.int32}
 
@@ -43,16 +45,14 @@ def reduce_fold_ref(x: jax.Array) -> jax.Array:
 
 
 def _fold_kernel(x_ref, o_ref):
-    x = x_ref[...]                              # (K, BLOCK_L)
+    def step(i, acc):                           # rows read from the ref:
+        return acc + x_ref[pl.ds(i, 1), :]      # (1, BLOCK_L)
 
-    def step(i, acc):
-        return acc + x[i]
-
-    o_ref[...] = jax.lax.fori_loop(1, x.shape[0], step, x[0])[None, :]
+    o_ref[...] = jax.lax.fori_loop(1, x_ref.shape[0], step, x_ref[0:1, :])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def reduce_fold_pallas(x: jax.Array, *, interpret: bool = INTERPRET
+def reduce_fold_pallas(x: jax.Array, *, interpret: Optional[bool] = None
                        ) -> jax.Array:
     """(K, L) -> (L,): the same left fold, tiled over the element axis.
     Pad lanes compute garbage that is sliced off — rows are folded in
@@ -66,7 +66,7 @@ def reduce_fold_pallas(x: jax.Array, *, interpret: bool = INTERPRET
         in_specs=[pl.BlockSpec((k, BLOCK_L), lambda i: (0, i))],
         out_specs=pl.BlockSpec((1, BLOCK_L), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, n + pad), x.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(xp)
     return out[0, :n]
 

@@ -6,6 +6,7 @@ FIPS-197 vectors, CRC32 checks zlib).
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict
 
 import jax
@@ -205,12 +206,15 @@ def dpi_scores_ref(payload: jax.Array, params: Dict) -> jax.Array:
             w3 (64,1) int8, s3 (); biases b1,b2 float32."""
     n, mtu = payload.shape
     beats = mtu // 64
+    # full f32 matmuls: the TPU's default f32 precision rounds operands
+    # to bfloat16, which no kernel could be held to
+    dot = functools.partial(jnp.dot, precision=jax.lax.Precision.HIGHEST)
     x = payload.reshape(n * beats, 64).astype(jnp.float32) / 128.0 - 1.0
-    h = jax.nn.relu(x @ (params["w1"].astype(jnp.float32) * params["s1"])
+    h = jax.nn.relu(dot(x, params["w1"].astype(jnp.float32) * params["s1"])
                     + params["b1"])
-    h = jax.nn.relu(h @ (params["w2"].astype(jnp.float32) * params["s2"])
+    h = jax.nn.relu(dot(h, params["w2"].astype(jnp.float32) * params["s2"])
                     + params["b2"])
-    y = h @ (params["w3"].astype(jnp.float32) * params["s3"])
+    y = dot(h, params["w3"].astype(jnp.float32) * params["s3"])
     return y[:, 0].reshape(n, beats)
 
 

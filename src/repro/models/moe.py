@@ -254,16 +254,7 @@ def _expert_shard_map_fn(cfg, compute_dtype, n_data: int, n_model: int,
 
 def _moe_chunked_shardmap(cfg, p, x, compute_dtype):
     """expert_sharding="ep_sm": explicit-collective MoE (see above)."""
-    import inspect
-    try:                                  # jax >= 0.6 top-level API
-        from jax import shard_map
-    except ImportError:                   # older jax: experimental module
-        from jax.experimental.shard_map import shard_map
-    # kwarg name changed check_rep -> check_vma; key off the signature,
-    # not the import location (the top-level alias predates the rename)
-    sm_kwargs = ({"check_vma": False}
-                 if "check_vma" in inspect.signature(shard_map).parameters
-                 else {"check_rep": False})
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.parallel.sharding import active_mesh
     mesh = active_mesh()
@@ -289,7 +280,7 @@ def _moe_chunked_shardmap(cfg, p, x, compute_dtype):
                   P("data", None, "model"), P("data", None, "model"),
                   P("data", "model", None)),
         out_specs=P("data"),
-        **sm_kwargs)
+        check_vma=False)
     # recompute the expert segment in the backward instead of stashing
     # the a2a/dispatch intermediates per chunk (the stash was ~5 GB/chunk
     # x 59 layers of extra memory traffic — measured via top_bytes)

@@ -340,6 +340,27 @@ def test_run_network_fused_mode_equivalent():
     assert not d, "run_network fused diverged:\n  " + "\n  ".join(d[:40])
 
 
+def test_default_width_incast_packs_and_matches_oracle():
+    """The paper's 8:1 incast at the node defaults (500 QPs, MTU 4096,
+    receiver window 64) needs more wire slots than any small world:
+    it must pack — no silent per-tick fallback — and stay bit-identical
+    to the oracle."""
+    fab = SwitchedFabric(9, FabricConfig(port_bandwidth=4, port_delay=2,
+                                         queue_capacity=32, seed=7))
+    recv = RdmaNode(0, fab, rx_credits=64)
+    senders = [RdmaNode(i + 1, fab, fc_window=16) for i in range(8)]
+    rng = np.random.default_rng(13)
+    for s in senders:
+        q, _rk, _buf = s.init_rdma(1 << 15, recv)
+        s.rdma_write(q, rng.integers(0, 256, 1 << 15, dtype=np.uint8))
+    nodes = [recv] + senders
+    assert recv.qp.tables.npsn.shape[0] == 500
+    assert fused.try_pack(nodes, 100_000, 8) is not None
+    res = assert_fused_matches_oracle(nodes)
+    assert res["idle_exit"]
+    assert fab.epochs.fused == 1 and fab.epochs.unfused == 0
+
+
 def test_engine_counter_contract_rides_the_carry():
     """PR 8 contract: engine counter columns (accepted / dup / ooo /
     credit-drop / ecn totals) are harvested at the epoch boundary and

@@ -109,7 +109,8 @@ from repro.models import params as P
 from repro.parallel import sharding as sh
 from repro.configs import get_smoke_config
 cfg = get_smoke_config('deepseek-v3-671b').replace(compute_dtype='float32')
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 p = P.init(moe.moe_spec(cfg), jax.random.key(0), "float32")
 x = jax.random.normal(jax.random.key(1), (4, 4096, cfg.d_model)) * 0.1
 with sh.activate(mesh, sh.make_rules("train"), "t"):

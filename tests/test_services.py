@@ -158,3 +158,29 @@ def test_crc_service_flags_corruption():
     flags = np.asarray(svc(jnp.asarray(pay),
                            jnp.asarray(np.full(4, 512, np.int32))))
     assert flags.shape == (4,)          # (integrity values, smoke only)
+
+
+@pytest.mark.parametrize("use_pallas", [False, True])
+@pytest.mark.parametrize("svc", ["aes-enc", "aes-dec", "dpi", "preproc",
+                                 "crc"])
+def test_service_rows_independent_of_padding(svc, use_pallas, dpi_params):
+    """RdmaNode runs the chain on its RX batch padded with zero rows and
+    keeps the first N rows: every service's rows must not see the pad."""
+    make = {"aes-enc": lambda: AesService(key=KEY),
+            "aes-dec": lambda: AesService(key=KEY, decrypt=True),
+            "dpi": lambda: DpiService(params=dpi_params),
+            "preproc": lambda: PreprocService(modulus=1000),
+            "crc": lambda: CrcService()}[svc]
+    service = make()
+    service.use_pallas = use_pallas
+    n, n_pad, mtu = 5, 16, 512
+    rng = np.random.default_rng(7)
+    pay = rng.integers(0, 256, (n, mtu), dtype=np.uint8)
+    plen = rng.integers(1, mtu + 1, n).astype(np.int32)
+    padded = np.zeros((n_pad, mtu), np.uint8)
+    padded[:n] = pay
+    plen_p = np.zeros(n_pad, np.int32)
+    plen_p[:n] = plen
+    alone = np.asarray(service(jnp.asarray(pay), jnp.asarray(plen)))
+    in_pad = np.asarray(service(jnp.asarray(padded), jnp.asarray(plen_p)))
+    np.testing.assert_array_equal(in_pad[:n], alone)
