@@ -1,0 +1,17 @@
+"""AES-128-ECB kernel: share of its roofline (bytes bound)."""
+from bench.harness import roofline_share
+from bench.metrics import kernel_counts
+
+# the Pallas call as the TPU trace names it: the jitted wrapper's name
+PATTERNS = ("%aes_ecb_pallas",)
+
+
+def read(ctx):
+    calls = ctx.calls.get("aes_ecb")
+    if ctx.trace is None or not calls:
+        return None
+    seconds, n = ctx.trace.op_seconds(PATTERNS)
+    if not n:
+        return None
+    flops, nbytes = kernel_counts.need("aes_ecb", calls)
+    return roofline_share(seconds, flops, nbytes, ctx.peak)
