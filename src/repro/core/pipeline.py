@@ -283,6 +283,7 @@ def _ensure_defaults(batch: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
 
 
 @partial(jax.jit, donate_argnums=(0,))
+@jax.named_scope("rx_engine")
 def rx_pipeline(tables: RxTables, batch: Dict[str, jax.Array]
                 ) -> Tuple[RxTables, RxResult]:
     """Per-packet oracle: scan the RX FSM over the batch in arrival
@@ -317,6 +318,7 @@ _OUT_BOOL = ("accept", "dup", "ooo", "dropped_credit", "rkey_err",
 
 
 @partial(jax.jit, donate_argnums=(0,))
+@jax.named_scope("rx_engine")
 def rx_pipeline_batched(tables: RxTables, batch: Dict[str, jax.Array]
                         ) -> Tuple[RxTables, RxResult]:
     """Batched multi-QP RX engine (the tentpole: paper §4.1 at scale).

@@ -38,6 +38,7 @@ import numpy as np
 
 from repro.core import packet as pk
 from repro.core import pipeline as pipe
+from repro.core import telemetry
 from repro.core.flow_control import (AckClockedFlowControl, CreditManager,
                                      DcqcnConfig, FlowControlConfig)
 from repro.core.qp import QPManager
@@ -66,6 +67,22 @@ class NodeStats:
 
     def snapshot(self) -> dict:
         """Common telemetry shape (see ``telemetry.MetricRegistry``)."""
+        return dataclasses.asdict(self)
+
+
+@dataclasses.dataclass
+class HostPathStats:
+    """What the per-tick host path cost, counted where the work is done.
+    Kept apart from ``NodeStats``, which the fused epoch core
+    (``repro.core.fused``) reproduces exactly: a fused epoch does this
+    work on the device, so these counts differ by execution mode."""
+    rx_batches: int = 0          # RX batches handed to the RX engine
+    chain_rows: int = 0          # rows handed to the service chain ...
+    chain_pad_rows: int = 0      # ... of which zero padding
+    h2d_puts: int = 0            # host arrays put on the device
+    d2h_reads: int = 0           # device arrays read back to the host
+
+    def snapshot(self) -> dict:
         return dataclasses.asdict(self)
 
 
@@ -143,6 +160,7 @@ class RdmaNode:
         self.services = services
         self.sniffer = sniffer
         self.stats = NodeStats()
+        self.host_stats = HostPathStats()
         self.recorder = None                 # telemetry.FlightRecorder
         self.qp_errors: set = set()                  # QPs dead on retry budget
         self._fatal_qps: set = set()                 # protection errors: never
@@ -196,7 +214,7 @@ class RdmaNode:
         host sync observability costs, and it happens here — at an epoch
         boundary, when a registry snapshot asks — never inside the
         per-batch engine calls."""
-        return {host: np.asarray(getattr(self.rx_tables, col))
+        return {host: self._to_host(getattr(self.rx_tables, col))
                 for col, host in ENGINE_COUNTERS.items()}
 
     def engine_totals(self) -> Dict[str, int]:
@@ -205,6 +223,7 @@ class RdmaNode:
     def snapshot(self) -> dict:
         """Common telemetry shape: every stats surface of the node."""
         return {"stats": self.stats.snapshot(),
+                "host": self.host_stats.snapshot(),
                 "engine": self.engine_totals(),
                 "fc": self.fc.snapshot(),
                 "credits": self.credits.snapshot(),
@@ -322,30 +341,34 @@ class RdmaNode:
                       data: np.ndarray, op: str = "write", coll=None):
         t = self.qp.tables
         start_psn = int(t.npsn[qpn])
-        rkey = self._remote_rkey[qpn]
-        pkts = pk.fragment_message(
-            int(t.remote_qpn[qpn]), start_psn, remote_addr, rkey, data,
-            op=op, mtu=self.mtu, src_ip=self.node_id,
-            dst_ip=int(t.remote_ip[qpn]), coll=coll,
-            addr_per_pkt=self._sr)
-        t.npsn[qpn] = (start_psn + len(pkts)) & pk.PSN_MASK
-        for p in pkts:
-            # retransmission buffer holds every payload until remote ACK
-            self.retx.hold(qpn, p, self.net.now)
-            self._send(qpn, p)
+        with telemetry.span("balboa.tx", qp=qpn, psn=start_psn,
+                            pkts=pk.read_resp_npkts(len(data), self.mtu)):
+            rkey = self._remote_rkey[qpn]
+            pkts = pk.fragment_message(
+                int(t.remote_qpn[qpn]), start_psn, remote_addr, rkey, data,
+                op=op, mtu=self.mtu, src_ip=self.node_id,
+                dst_ip=int(t.remote_ip[qpn]), coll=coll,
+                addr_per_pkt=self._sr)
+            t.npsn[qpn] = (start_psn + len(pkts)) & pk.PSN_MASK
+            for p in pkts:
+                # retransmission buffer holds every payload until remote ACK
+                self.retx.hold(qpn, p, self.net.now)
+                self._send(qpn, p)
 
     def _emit_read_request(self, qpn: int, remote_addr: int, length: int):
         t = self.qp.tables
         psn = int(t.npsn[qpn])
-        p = pk.make_read_request(int(t.remote_qpn[qpn]), psn, remote_addr,
-                                 self._remote_rkey[qpn], length,
-                                 src_ip=self.node_id,
-                                 dst_ip=int(t.remote_ip[qpn]))
-        # responder will stream n_pkts of responses; budget accounted as 1
-        t.npsn[qpn] = (psn + 1) & pk.PSN_MASK
-        self._read_pending[qpn] = length
-        self.retx.hold(qpn, p, self.net.now)
-        self._send(qpn, p)
+        with telemetry.span("balboa.tx", qp=qpn, psn=psn, pkts=1):
+            p = pk.make_read_request(int(t.remote_qpn[qpn]), psn,
+                                     remote_addr, self._remote_rkey[qpn],
+                                     length, src_ip=self.node_id,
+                                     dst_ip=int(t.remote_ip[qpn]))
+            # responder will stream n_pkts of responses; budget accounted
+            # as 1
+            t.npsn[qpn] = (psn + 1) & pk.PSN_MASK
+            self._read_pending[qpn] = length
+            self.retx.hold(qpn, p, self.net.now)
+            self._send(qpn, p)
 
     def _send(self, local_qpn: int, p: pk.Packet):
         self.stats.tx_pkts += 1
@@ -388,22 +411,22 @@ class RdmaNode:
                 self.sniffer.capture(p, self.net.now, direction="rx")
         # control-plane packets (ACK/NAK) handled on the control path
         data_pkts = []
-        for p in pkts:
-            if p.opcode == pk.ACK:
-                self._on_ack(p)
-            elif p.opcode == pk.NAK:
-                self._on_nak(p)
-            elif p.opcode == pk.NAK_PROT:
-                self._on_nak_prot(p)
-            elif p.opcode == pk.CNP:
-                self._on_cnp(p)
-            elif p.opcode == pk.READ_REQUEST:
-                self._on_read_request(p)
-            else:
-                data_pkts.append(p)
+        with telemetry.span("balboa.rx.ctrl"):
+            for p in pkts:
+                if p.opcode == pk.ACK:
+                    self._on_ack(p)
+                elif p.opcode == pk.NAK:
+                    self._on_nak(p)
+                elif p.opcode == pk.NAK_PROT:
+                    self._on_nak_prot(p)
+                elif p.opcode == pk.CNP:
+                    self._on_cnp(p)
+                elif p.opcode == pk.READ_REQUEST:
+                    self._on_read_request(p)
+                else:
+                    data_pkts.append(p)
         if not data_pkts:
             return
-        batch_np = pk.batch_from_packets(data_pkts, self.mtu)
         n = len(data_pkts)
         # pad to the next power-of-two multiple of RX_PAD: bounds the
         # number of distinct jit shapes of the RX pipeline
@@ -411,41 +434,56 @@ class RdmaNode:
         while target < n:
             target *= 2
         pad = target - n
-        if pad:
-            for k, v in batch_np.items():
-                batch_np[k] = np.concatenate(
-                    [v, np.zeros((pad,) + v.shape[1:], v.dtype)])
-            batch_np["valid"][n:] = 0
-        batch = {k: jnp.asarray(v) for k, v in batch_np.items()}
-        # sync credits from the host-side credit manager
-        self.rx_tables = self.rx_tables._replace(
-            credits=jnp.asarray(self.credits.credits, jnp.int32))
-        self.rx_tables, res = self._rx_pipe(self.rx_tables, batch)
-        res = res._asdict()
-        ecn_cnt = np.asarray(res.pop("ecn_cnt"))     # (Q,) per-QP CE tally
-        res = {k: np.asarray(v)[:n] for k, v in res.items()}
-        self.credits.credits = list(np.asarray(self.rx_tables.credits))
-        # attribute CE marks to the spine that carried them, so the CNP
-        # can steer the sender's per-path rate cut (ecn_cnt only says
-        # *which QP*; the packet's path_id says which plane)
-        ce_path: Dict[int, int] = {}
-        for p in data_pkts:
-            if p.ecn and p.opcode in pk.PAYLOAD_OPS:
-                ce_path[p.qpn] = p.path_id
-        self._emit_cnps(ecn_cnt, ce_path)
+        with telemetry.span("balboa.rx.stage"):
+            batch_np = pk.batch_from_packets(data_pkts, self.mtu)
+            if pad:
+                for k, v in batch_np.items():
+                    batch_np[k] = np.concatenate(
+                        [v, np.zeros((pad,) + v.shape[1:], v.dtype)])
+                batch_np["valid"][n:] = 0
+            batch = {k: self._to_device(v) for k, v in batch_np.items()}
+            # sync credits from the host-side credit manager
+            self.rx_tables = self.rx_tables._replace(
+                credits=self._to_device(self.credits.credits, jnp.int32))
+        with telemetry.span("balboa.rx.sync", rows=target, pad=pad):
+            self.rx_tables, res = self._rx_pipe(self.rx_tables, batch)
+            self.host_stats.rx_batches += 1
+            res = res._asdict()
+            ecn_cnt = self._to_host(res.pop("ecn_cnt"))  # (Q,) CE tally
+            res = {k: self._to_host(v)[:n] for k, v in res.items()}
+            self.credits.credits = list(self._to_host(self.rx_tables.credits))
+            # attribute CE marks to the spine that carried them, so the
+            # CNP can steer the sender's per-path rate cut (ecn_cnt only
+            # says *which QP*; the packet's path_id says which plane)
+            ce_path: Dict[int, int] = {}
+            for p in data_pkts:
+                if p.ecn and p.opcode in pk.PAYLOAD_OPS:
+                    ce_path[p.qpn] = p.path_id
+            self._emit_cnps(ecn_cnt, ce_path)
 
-        # ---- service chain over the accepted payload stream -------------
-        # (services are row-independent: the chain runs on the padded
-        # batch, so it compiles once per pad size, not per packet count)
-        payload = batch_np["payload"][:n]
-        flags = np.zeros(n, np.int64)
-        if self.services is not None:
-            out, f = self.services.process(jnp.asarray(batch_np["payload"]),
-                                           jnp.asarray(batch_np["plen"]))
-            payload = np.asarray(out)[:n]
-            flags = np.asarray(f)[:n]
+            # ---- service chain over the accepted payload stream ---------
+            # (services are row-independent: the chain runs on the padded
+            # batch, so it compiles once per pad size, not per packet
+            # count)
+            payload = batch_np["payload"][:n]
+            flags = np.zeros(n, np.int64)
+            if self.services is not None:
+                self.host_stats.chain_rows += target
+                self.host_stats.chain_pad_rows += pad
+                out, f = self.services.process(
+                    self._to_device(batch_np["payload"]),
+                    self._to_device(batch_np["plen"]))
+                payload = self._to_host(out)[:n]
+                flags = self._to_host(f)[:n]
 
-        # ---- DMA accepted payloads into registered memory ----------------
+        with telemetry.span("balboa.rx.land") as s:
+            s.set_metadata(done=self._land(data_pkts, res, payload, flags))
+
+    def _land(self, data_pkts, res, payload, flags) -> int:
+        """DMA accepted payloads into registered memory, answer each
+        packet (ACK, NAK, NAK_PROT), complete messages.  Returns how many
+        messages completed."""
+        done = 0
         for i, p in enumerate(data_pkts):
             qpn = p.qpn
             if res["accept"][i]:
@@ -485,6 +523,7 @@ class RdmaNode:
                     else:
                         self._completions[qpn] = \
                             self._completions.get(qpn, 0) + 1
+                        done += 1
                         self._rec("completion", qpn, psn=p.psn)
             elif res["dup"][i]:
                 self.stats.dup_dropped += 1
@@ -508,7 +547,18 @@ class RdmaNode:
                                                  int(res["ack_psn"][i]),
                                                  nak=True))
         if self._sr and self._sr_pending_last:
-            self._flush_sr_completions()
+            done += self._flush_sr_completions()
+        return done
+
+    def _to_device(self, x, dtype=None):
+        """Put a host array on the device (counted)."""
+        self.host_stats.h2d_puts += 1
+        return jnp.asarray(x, dtype)
+
+    def _to_host(self, x) -> np.ndarray:
+        """Read a device array back to the host (counted)."""
+        self.host_stats.d2h_reads += 1
+        return np.asarray(x)
 
     # ---- selective-repeat host bookkeeping -----------------------------
     def _sr_note_progress(self, qpn: int, a: int, ln: int):
@@ -531,13 +581,14 @@ class RdmaNode:
         if not pend:
             self._sr_pend.pop(qpn, None)
 
-    def _flush_sr_completions(self):
+    def _flush_sr_completions(self) -> int:
         """Deferred message completions: a LAST/ONLY fragment accepted
         out of order completes only when the receive window's cumulative
         edge (epsn) has passed it — i.e. every fragment before it
-        landed."""
+        landed.  Returns how many completed."""
         span = pk.PSN_MASK + 1
-        epsn_col = np.asarray(self.rx_tables.epsn)
+        epsn_col = self._to_host(self.rx_tables.epsn)
+        completed = 0
         for qpn in list(self._sr_pending_last):
             epsn = int(epsn_col[qpn])
             lst = self._sr_pending_last[qpn]
@@ -547,6 +598,7 @@ class RdmaNode:
                 continue
             self._completions[qpn] = self._completions.get(qpn, 0) \
                 + len(done)
+            completed += len(done)
             for ps in done:
                 self._rec("completion", qpn, psn=ps)
             rest = [ps for ps in lst
@@ -555,6 +607,7 @@ class RdmaNode:
                 self._sr_pending_last[qpn] = rest
             else:
                 del self._sr_pending_last[qpn]
+        return completed
 
     def _on_ack(self, p: pk.Packet):
         qpn = self._local_qpn(p.qpn)
@@ -779,12 +832,14 @@ def step_network(nodes: List[RdmaNode]) -> None:
     with completion-watermark polls to process data *as it arrives*
     instead of store-and-forwarding whole transfers."""
     net = nodes[0].net
-    delivered = net.tick()
+    with telemetry.span("balboa.fabric"):
+        delivered = net.tick()
     for (src, dst), pkts in delivered.items():
         if pkts:
             nodes[dst].on_packets(pkts)
     for nd in nodes:
-        nd.tick()
+        with telemetry.span("balboa.timers"):
+            nd.tick()
 
 
 def network_pending(nodes: List[RdmaNode]) -> bool:

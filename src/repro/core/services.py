@@ -190,17 +190,26 @@ class ServiceChain:
             if name in self.flag_bits:       # duplicate service names
                 name = f"{name}@{bit}"
             self.flag_bits[name] = bit
-        self._jitted = jax.jit(self._process)
+        # the device trace names the program after this function
+        # (``jit_service_chain``) and each service's ops after its name
+        process = self._process
+
+        def service_chain(payload, plen):
+            return process(payload, plen)
+        self._jitted = jax.jit(service_chain)
 
     def _process(self, payload, plen):
         flags = jnp.zeros(payload.shape[0], jnp.int32)
         for svc, bit in zip(self.parallel, self._par_bits):
-            flags = flags | (svc(payload, plen) << bit)
+            with jax.named_scope(svc.name):
+                flags = flags | (svc(payload, plen) << bit)
         out = payload
         for svc in self.on_path:
-            out = svc(out, plen)
+            with jax.named_scope(svc.name):
+                out = svc(out, plen)
         for svc, bit in zip(self.parallel_after, self._par_after_bits):
-            flags = flags | (svc(out, plen) << bit)
+            with jax.named_scope(svc.name):
+                flags = flags | (svc(out, plen) << bit)
         return out, flags
 
     def process(self, payload, plen):

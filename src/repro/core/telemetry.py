@@ -31,6 +31,12 @@ there is NO wall-clock anywhere in ``repro.core`` (enforced by
 ``tests/test_telemetry.py``), so two runs of the same seeded config
 produce byte-identical trace exports.
 
+``span``
+    Program spans on the profiler's clock: a named interval around a
+    call, a batch or a tick of the host path (``SPANS``), recorded by
+    ``jax.profiler`` only while a profiler session is open, so each gap
+    in a device trace can be put down to what the host was doing.
+
 FPGA -> TPU design dual: the FPGA taps counters out of BRAM next to
 each pipeline stage and streams trace words over a dedicated DMA ring;
 here the same per-stage counters ride the jitted engines' carried state
@@ -44,6 +50,8 @@ import collections
 import dataclasses
 import json
 from typing import Callable, Dict, List, Optional, Tuple, Union
+
+import jax
 
 # ---------------------------------------------------------------------------
 # Typed metrics
@@ -220,6 +228,32 @@ EVENT_KINDS = (
     "stream_issue", "stream_tile", "stream_done", "stream_refetch",
     "coll_transfer",
 )
+
+# program spans: what the host path does between device calls.  A span
+# wraps a call, a batch or a tick, never a single packet; spans of one
+# thread nest by time, and a span's self time is its duration minus
+# what its ``balboa.*`` children cover (a ``balboa.tx`` opened while an
+# ACK is handled is transmit time, not control time).
+SPANS = (
+    "balboa.fabric",      # step_network: the fabric's tick (net.tick)
+    "balboa.timers",      # step_network: one node's timer tick
+    "balboa.rx.ctrl",     # on_packets: ACK/NAK/CNP/READ_REQUEST dispatch
+    "balboa.rx.stage",    # on_packets: batch build, padding, host->device
+    "balboa.rx.sync",     # on_packets: RX engine and chain, read back
+    "balboa.rx.land",     # on_packets: landing, ACKs, completions
+    "balboa.tx",          # one message or READ request packetised, sent
+)
+
+
+def span(name: str, **stats):
+    """A program span ``name`` (one of ``SPANS``) with integer ``stats``
+    attached, as a context manager.  A thin
+    ``jax.profiler.TraceAnnotation``: it records on the profiler's clock
+    (the device trace's) while a profiler session is open and nothing
+    otherwise, so ``repro.core`` reads no clock of its own.  Stats known
+    only at the end are added with ``set_metadata`` on the entered span."""
+    return jax.profiler.TraceAnnotation(name, **stats)
+
 
 Track = Tuple[str, Union[int, str]]      # (category, instance)
 

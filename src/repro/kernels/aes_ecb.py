@@ -113,6 +113,7 @@ def aes_ecb_pallas(blocks: jax.Array, round_keys, *, decrypt: bool = False,
         ],
         out_specs=pl.BlockSpec((tile, 128), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows_p, 128), jnp.int32),
+        name="aes_ecb_pallas",
         interpret=interpret_mode(interpret),
     )(x, rk, sbox)
     return out.reshape(-1, 16)[:n].astype(jnp.uint8)

@@ -96,6 +96,7 @@ def crc32_pallas(payload: jax.Array, plen: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((1, BLOCK_N), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, n_p), jnp.int32),
+        name="crc32_pallas",
         interpret=interpret_mode(interpret),
     )(words, pl2, jnp.asarray(_TABLES))
     return lax.bitcast_convert_type(out[0, :n], jnp.uint32)

@@ -71,6 +71,7 @@ def dpi_scores_pallas(payload: jax.Array, params: Dict, *,
         ],
         out_specs=pl.BlockSpec((BLOCK_B, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m + pad, 1), jnp.float32),
+        name="dpi_scores_pallas",
         interpret=interpret_mode(interpret),
     )(x, params["w1"].astype(jnp.int32), params["b1"],
       params["w2"].astype(jnp.int32), params["b2"],

@@ -102,6 +102,7 @@ def fused_decrypt_dpi_pallas(payload: jax.Array, round_keys,
             jax.ShapeDtypeStruct((n + pad, mtu), jnp.int32),
             jax.ShapeDtypeStruct((n + pad, 1), jnp.float32),
         ],
+        name="fused_decrypt_dpi_pallas",
         interpret=interpret_mode(interpret),
     )(x, rk, inv_sbox, iidx,
       dpi_params["w1"].astype(jnp.int32), dpi_params["b1"],

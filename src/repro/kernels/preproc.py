@@ -49,6 +49,7 @@ def preproc_pallas(recs: jax.Array, n_dense: int, modulus: int, *,
         in_specs=[pl.BlockSpec((BLOCK_M, rw), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((BLOCK_M, rw), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m + pad, rw), jnp.int32),
+        name="preproc_pallas",
         interpret=interpret_mode(interpret),
     )(x)
     return out[:m]

@@ -66,6 +66,7 @@ def reduce_fold_pallas(x: jax.Array, *, interpret: Optional[bool] = None
         in_specs=[pl.BlockSpec((k, BLOCK_L), lambda i: (0, i))],
         out_specs=pl.BlockSpec((1, BLOCK_L), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, n + pad), x.dtype),
+        name="reduce_fold_pallas",
         interpret=interpret_mode(interpret),
     )(xp)
     return out[0, :n]
