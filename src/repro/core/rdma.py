@@ -33,6 +33,7 @@ import dataclasses
 import os
 from typing import Dict, List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -46,6 +47,37 @@ from repro.core.retransmit import RetransmissionBuffer
 from repro.core.services import ServiceChain
 
 RX_PAD = 16           # pad RX batches to multiples of this (jit stability)
+READBACK_WIDTH = 128  # row width of a read-back that carries no payload
+PER_QP_COLS = ("ecn_cnt", "credits", "epsn")   # read back per QP, not per row
+
+
+def _as_bytes(a: jax.Array) -> jax.Array:
+    """The bytes of ``a``, flat (bools as one byte of 0 or 1)."""
+    if a.dtype == jnp.bool_:
+        a = a.astype(jnp.uint8)
+    elif a.dtype != jnp.uint8:
+        a = jax.lax.bitcast_convert_type(a, jnp.uint8)
+    return a.reshape(-1)
+
+
+def _pack_order(cols) -> List[str]:
+    """``cols``' names, widest items first, so that every host view of
+    the packed bytes starts at a multiple of its item size."""
+    return sorted(cols, key=lambda k: -np.dtype(cols[k].dtype).itemsize)
+
+
+@jax.jit
+def rx_readback(cols, rows):
+    """Pack one RX batch's results into one (R, W) uint8 array, so that
+    they reach the host in one transfer: the bytes of each array of
+    ``cols`` in order, zero-padded to whole rows, then ``rows`` (an
+    (N, W) uint8 array, or None) as it is.  W is ``rows``' width, or
+    ``READBACK_WIDTH``; keeping the payload's rows whole spares the
+    device a relayout of the widest array."""
+    w = READBACK_WIDTH if rows is None else rows.shape[1]
+    head = jnp.concatenate([_as_bytes(a) for a in cols])
+    head = jnp.pad(head, (0, -head.size % w)).reshape(-1, w)
+    return head if rows is None else jnp.concatenate([head, rows])
 
 
 @dataclasses.dataclass
@@ -195,6 +227,9 @@ class RdmaNode:
         self._sr_pending_last: Dict[int, List[int]] = {}
         self._last_gap_resend: Dict[int, int] = {}   # qpn -> tick
         self._path_rr: Dict[int, int] = {}           # qpn -> spray cursor
+        # largest padded RX batch whose read-back, and every smaller
+        # one's, is compiled (``_warm_read_back``)
+        self._readback_rows = 0
 
     # --------------------------------------------------------- telemetry
     def attach_recorder(self, rec):
@@ -448,10 +483,22 @@ class RdmaNode:
         with telemetry.span("balboa.rx.sync", rows=target, pad=pad):
             self.rx_tables, res = self._rx_pipe(self.rx_tables, batch)
             self.host_stats.rx_batches += 1
-            res = res._asdict()
-            ecn_cnt = self._to_host(res.pop("ecn_cnt"))  # (Q,) CE tally
-            res = {k: self._to_host(v)[:n] for k, v in res.items()}
-            self.credits.credits = list(self._to_host(self.rx_tables.credits))
+            cols = res._asdict()             # per row, and ecn_cnt per QP
+            cols["credits"] = self.rx_tables.credits
+            if self._sr:
+                cols["epsn"] = self.rx_tables.epsn
+            # ---- service chain over the accepted payload stream ---------
+            # dispatched behind the RX engine without waiting for it
+            # (services are row-independent: the chain runs on the padded
+            # batch, so it compiles once per pad size, not per packet
+            # count)
+            out = None
+            if self.services is not None:
+                self.host_stats.chain_rows += target
+                self.host_stats.chain_pad_rows += pad
+                out, cols["flags"] = self.services.process(
+                    self._to_device(batch_np["payload"]),
+                    self._to_device(batch_np["plen"]))
             # attribute CE marks to the spine that carried them, so the
             # CNP can steer the sender's per-path rate cut (ecn_cnt only
             # says *which QP*; the packet's path_id says which plane)
@@ -459,27 +506,61 @@ class RdmaNode:
             for p in data_pkts:
                 if p.ecn and p.opcode in pk.PAYLOAD_OPS:
                     ce_path[p.qpn] = p.path_id
+            if target > self._readback_rows:
+                self._warm_read_back(cols, out, target)
+            host, out = self._read_back(cols, out)
+            ecn_cnt = host.pop("ecn_cnt")                 # (Q,) CE tally
+            self.credits.credits = list(host.pop("credits"))
+            epsn = host.pop("epsn", None)
+            if out is None:
+                payload, flags = batch_np["payload"][:n], np.zeros(n, np.int64)
+            else:
+                payload, flags = out[:n], host.pop("flags")[:n]
+            res = {k: v[:n] for k, v in host.items()}
             self._emit_cnps(ecn_cnt, ce_path)
 
-            # ---- service chain over the accepted payload stream ---------
-            # (services are row-independent: the chain runs on the padded
-            # batch, so it compiles once per pad size, not per packet
-            # count)
-            payload = batch_np["payload"][:n]
-            flags = np.zeros(n, np.int64)
-            if self.services is not None:
-                self.host_stats.chain_rows += target
-                self.host_stats.chain_pad_rows += pad
-                out, f = self.services.process(
-                    self._to_device(batch_np["payload"]),
-                    self._to_device(batch_np["plen"]))
-                payload = self._to_host(out)[:n]
-                flags = self._to_host(f)[:n]
-
         with telemetry.span("balboa.rx.land") as s:
-            s.set_metadata(done=self._land(data_pkts, res, payload, flags))
+            s.set_metadata(done=self._land(data_pkts, res, payload, flags,
+                                           epsn))
 
-    def _land(self, data_pkts, res, payload, flags) -> int:
+    def _warm_read_back(self, cols, rows, target: int):
+        """Compile ``rx_readback`` ahead for each padded batch size below
+        ``target`` that this node has not met (``target`` itself compiles
+        on its first call).  Batch sizes wander with loss and load, so a
+        size first met mid-run would otherwise be compiled there, in the
+        middle of the traffic."""
+        def spec(a, n):
+            return jax.ShapeDtypeStruct((n,) + a.shape[1:], a.dtype)
+        size = RX_PAD
+        while size < target:
+            if size > self._readback_rows:
+                c = {k: spec(a, a.shape[0] if k in PER_QP_COLS else size)
+                     for k, a in cols.items()}
+                rx_readback.lower(tuple(c[k] for k in _pack_order(c)),
+                                  None if rows is None
+                                  else spec(rows, size)).compile()
+            size *= 2
+        self._readback_rows = target
+
+    def _read_back(self, cols: Dict[str, jax.Array], rows):
+        """Read ``cols`` and ``rows`` back in one transfer
+        (``rx_readback``) and split it on the host into numpy views of
+        their own dtypes and shapes.  Returns (``cols`` by name, ``rows``
+        or None)."""
+        names = _pack_order(cols)
+        flat = self._to_host(rx_readback(tuple(cols[k] for k in names),
+                                         rows)).reshape(-1)
+        out, off = {}, 0
+        for k in names:
+            dt = np.dtype(cols[k].dtype)
+            nbytes = cols[k].size * dt.itemsize
+            out[k] = flat[off:off + nbytes].view(dt).reshape(cols[k].shape)
+            off += nbytes
+        if rows is None:
+            return out, None
+        return out, flat[flat.size - rows.size:].reshape(rows.shape)
+
+    def _land(self, data_pkts, res, payload, flags, epsn) -> int:
         """DMA accepted payloads into registered memory, answer each
         packet (ACK, NAK, NAK_PROT), complete messages.  Returns how many
         messages completed."""
@@ -547,7 +628,7 @@ class RdmaNode:
                                                  int(res["ack_psn"][i]),
                                                  nak=True))
         if self._sr and self._sr_pending_last:
-            done += self._flush_sr_completions()
+            done += self._flush_sr_completions(epsn)
         return done
 
     def _to_device(self, x, dtype=None):
@@ -581,13 +662,13 @@ class RdmaNode:
         if not pend:
             self._sr_pend.pop(qpn, None)
 
-    def _flush_sr_completions(self) -> int:
+    def _flush_sr_completions(self, epsn_col: np.ndarray) -> int:
         """Deferred message completions: a LAST/ONLY fragment accepted
         out of order completes only when the receive window's cumulative
-        edge (epsn) has passed it — i.e. every fragment before it
-        landed.  Returns how many completed."""
+        edge (``epsn_col``, per QP, as the batch left it) has passed it
+        — i.e. every fragment before it landed.  Returns how many
+        completed."""
         span = pk.PSN_MASK + 1
-        epsn_col = self._to_host(self.rx_tables.epsn)
         completed = 0
         for qpn in list(self._sr_pending_last):
             epsn = int(epsn_col[qpn])

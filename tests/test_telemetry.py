@@ -372,11 +372,11 @@ class _RecordingChain:
 
 
 def _two_node_flow(chain=None, rx_mode="go_back_n", flows=4, msg=3000,
-                   loss=0.05, seed=11):
+                   loss=0.05, seed=11, n_qps=8):
     from repro.core.netsim import LinkConfig, Network
     from repro.core.rdma import RdmaNode
     net = Network(2, LinkConfig(loss_prob=loss, latency_ticks=2, seed=seed))
-    kw = dict(n_qps=8, mtu=256, fc_window=8, rx_credits=16,
+    kw = dict(n_qps=n_qps, mtu=256, fc_window=8, rx_credits=16,
               rx_mode=rx_mode)
     a = RdmaNode(0, net, **kw)
     b = RdmaNode(1, net, services=chain, **kw)
@@ -440,6 +440,180 @@ def test_d2h_reads_match_a_patched_asarray(rx_mode, monkeypatch):
     got = sum(nd.host_stats.d2h_reads for nd in nodes) - before
     assert got > 0
     assert got == seen[0] - chain_reads
+
+
+@pytest.mark.parametrize("rx_mode", ["go_back_n", "selective_repeat"])
+def test_one_read_back_per_rx_batch(rx_mode):
+    """Each RX batch reaches the host in one read: on every tick of a
+    lossy flow through a chain, each node's ``d2h_reads`` grows by as
+    much as its ``rx_batches``."""
+    from repro.core.rdma import network_pending, step_network
+    nodes = _two_node_flow(_RecordingChain(), rx_mode=rx_mode, msg=20000)
+    for _ in range(3000):
+        before = [(nd.host_stats.d2h_reads, nd.host_stats.rx_batches)
+                  for nd in nodes]
+        step_network(nodes)
+        for nd, (d2h, batches) in zip(nodes, before):
+            assert (nd.host_stats.d2h_reads - d2h
+                    == nd.host_stats.rx_batches - batches)
+        if not network_pending(nodes):
+            break
+    b = nodes[1]
+    assert sum(b._completions.values()) == 4 * b.expected_completions(20000)
+    assert b.host_stats.rx_batches > 10
+
+
+def _xor_flag_chain():
+    """A chain whose output and flags both depend on the payload: XOR
+    on the path, then an inspector flagging rows whose first byte is
+    odd."""
+    from repro.core.services import (OnPathService, ParallelPathService,
+                                      ServiceChain)
+
+    class Xor(OnPathService):
+        name = "xor"
+
+        def __call__(self, payload, plen):
+            return payload ^ jnp.uint8(0x5A)
+
+    class Odd(ParallelPathService):
+        name = "odd"
+
+        def __call__(self, payload, plen):
+            return (payload[:, 0] & 1).astype(jnp.int32)
+
+    return ServiceChain(on_path=[Xor()], parallel_after=[Odd()])
+
+
+def _separate_reads(node, cols, rows):
+    """``RdmaNode._read_back`` as one read per device array."""
+    return ({k: node._to_host(v) for k, v in cols.items()},
+            None if rows is None else node._to_host(rows))
+
+
+# flows -> the padded RX batch sizes they must reach
+_READBACK_FLOWS = {
+    "narrow": (dict(flows=7), {16, 64}),
+    "wide": (dict(n_qps=41, flows=40, msg=2048), {512}),
+}
+
+
+@pytest.mark.parametrize("flow", sorted(_READBACK_FLOWS))
+@pytest.mark.parametrize("chained", [True, False])
+@pytest.mark.parametrize("rx_mode", ["go_back_n", "selective_repeat"])
+def test_packed_read_back_matches_separate_reads(rx_mode, chained, flow,
+                                                 monkeypatch):
+    """The one packed read of an RX batch gives every result column,
+    ``ecn_cnt``, ``credits``, ``epsn``, payload and flags exactly as a
+    separate read of the same device array does; a lossy flow landed
+    through it ends as one landed through separate reads: same stats,
+    credits, completions and landed bytes."""
+    from repro.core.rdma import RdmaNode, run_network
+    kw, want_rows = _READBACK_FLOWS[flow]
+    packed = RdmaNode._read_back
+    rows_seen = set()
+
+    def checked(node, cols, rows):
+        host, out = packed(node, cols, rows)
+        assert set(host) == set(cols)
+        pairs = [(host[k], cols[k]) for k in cols]
+        if rows is None:
+            assert out is None
+        else:
+            pairs.append((out, rows))
+        for got, dev in pairs:
+            want = np.asarray(dev)
+            assert got.dtype == want.dtype and got.shape == want.shape
+            assert np.array_equal(got, want)
+        assert ("epsn" in cols) == (rx_mode == "selective_repeat")
+        assert ("flags" in cols) == chained == (rows is not None)
+        rows_seen.add(cols["accept"].shape[0])
+        return host, out
+
+    def run(read_back):
+        monkeypatch.setattr(RdmaNode, "_read_back", read_back)
+        nodes = _two_node_flow(_xor_flag_chain() if chained else None,
+                               rx_mode=rx_mode, **kw)
+        run_network(nodes, max_ticks=3000)
+        return nodes
+
+    nodes = run(checked)
+    assert want_rows <= rows_seen, rows_seen
+    b = nodes[1]
+    msg = kw.get("msg", 3000)
+    assert sum(b._completions.values()) == \
+        kw["flows"] * b.expected_completions(msg)
+    assert (b.stats.dpi_flagged > 0) == chained
+    for nd, ref in zip(nodes, run(_separate_reads)):
+        assert nd.stats == ref.stats
+        assert nd.credits.credits == ref.credits.credits
+        assert nd._completions == ref._completions
+        assert nd._rx_progress == ref._rx_progress
+        got, want = nd.host_stats.snapshot(), ref.host_stats.snapshot()
+        assert got.pop("d2h_reads") <= want.pop("d2h_reads")
+        assert got == want
+        assert nd._qp_buffer.keys() == ref._qp_buffer.keys()
+        for q, (_, buf) in nd._qp_buffer.items():
+            assert np.array_equal(buf, ref._qp_buffer[q][1])
+
+
+def test_read_back_compiles_each_batch_size_before_it_is_met():
+    """The first RX batch larger than any before compiles the read-back
+    for its size and every smaller padded size, each once; a batch of a
+    size met before, or smaller, lowers nothing."""
+    import jax
+    from repro.core.rdma import RX_PAD, network_pending, step_network
+    lowered, on = [], [True]
+
+    def listen(event, _secs, fun_name=None, **_kw):
+        if on[0] and fun_name == "jit(rx_readback)" and \
+                event.endswith("jaxpr_to_mlir_module_duration"):
+            lowered.append(fun_name)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        # 37 QPs: shapes no other test compiles
+        nodes = _two_node_flow(n_qps=37, flows=36, msg=8192)
+        b = nodes[1]
+        quiet_batches = 0
+        for _ in range(3000):
+            seen, batches, n0 = b._readback_rows, b.host_stats.rx_batches, \
+                len(lowered)
+            step_network(nodes)
+            if b._readback_rows == seen:
+                assert len(lowered) == n0
+                quiet_batches += b.host_stats.rx_batches - batches
+            if not network_pending(nodes):
+                break
+    finally:
+        on[0] = False
+    assert b._readback_rows == 512 and quiet_batches > 10
+    assert len(lowered) == (512 // RX_PAD).bit_length()   # 16, 32, ..., 512
+
+
+@pytest.mark.parametrize("rows", [16, 64, 512])
+def test_read_back_keeps_every_bit(rows):
+    """Random bit patterns of each dtype the RX path reads back (bool,
+    int32, uint8 payload rows) come back exactly, with and without
+    payload rows."""
+    from repro.core.netsim import LinkConfig, Network
+    from repro.core.rdma import RdmaNode
+    node = RdmaNode(0, Network(1, LinkConfig()), n_qps=500)
+    rng = np.random.default_rng(rows)
+    cols = {"accept": rng.integers(0, 2, rows).astype(bool),
+            "dma_addr": rng.integers(-2**31, 2**31, rows, dtype=np.int32),
+            "ecn_cnt": rng.integers(-2**31, 2**31, 500, dtype=np.int32),
+            "send_ack": rng.integers(0, 2, rows).astype(bool)}
+    payload = rng.integers(0, 256, (rows, 4096), dtype=np.uint8)
+    for with_rows in (False, True):
+        host, out = node._read_back(
+            {k: jnp.asarray(v) for k, v in cols.items()},
+            jnp.asarray(payload) if with_rows else None)
+        for k, v in cols.items():
+            assert host[k].dtype == v.dtype and np.array_equal(host[k], v)
+        assert out is None if not with_rows else \
+            np.array_equal(out, payload)
+    assert node.host_stats.d2h_reads == 2
 
 
 def test_profiled_ticks_hold_nested_spans_with_stats(tmp_path):
