@@ -41,7 +41,9 @@ per epoch (see BENCH_sync_census.json before/after).
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
+import heapq
 from functools import lru_cache, partial
 from typing import Dict, List, Optional, Tuple
 
@@ -54,16 +56,20 @@ from jax import lax
 from repro.core import chaos
 from repro.core import netsim
 from repro.core import packet as pk
+from repro.core import telemetry
 from repro.core.pipeline import _STATE_FIELDS, _rx_decide
+from repro.core.rdma import RdmaNode
+from repro.core.retransmit import RetransmissionBuffer, _Slot
 
 MASK = pk.PSN_MASK
 SPAN = MASK + 1
 HALF = MASK // 2
 NEG = -(10 ** 9)             # "never happened" holdoff sentinel (rdma.py)
-MAX_RETRIES = 16             # retransmit.RetransmissionBuffer.MAX_RETRIES
-NAK_HOLDOFF = 8              # rdma.RdmaNode.NAK_HOLDOFF
-CNP_HOLDOFF = 8              # rdma.RdmaNode.CNP_HOLDOFF
 BIG = np.int32(2 ** 31 - 1)  # sort key for not-due wire slots
+# Sorts in one ``_wire_due_perm`` (two argsorts), which the star fabric's
+# tick calls once and which nothing else in the epoch program sorts
+# besides: the device trace counts ticks by them.
+WIRE_SORTS_PER_TICK = 2
 
 _LAST_OPS = (pk.WRITE_LAST, pk.WRITE_ONLY,
              pk.READ_RESP_LAST, pk.READ_RESP_ONLY)
@@ -828,7 +834,7 @@ def make_epoch_fn(skey: ShapeKey):
     def _bump_send(c, f, row):
         r = c["p_retr"][f, row] + 1
         c = _up(c, p_retr=c["p_retr"].at[f, row].set(r))
-        exh = r > MAX_RETRIES
+        exh = r > RetransmissionBuffer.MAX_RETRIES
         c = _up(c, abort=c["abort"] | exh.astype(jnp.int32))
 
         def fire(c):
@@ -863,7 +869,8 @@ def make_epoch_fn(skey: ShapeKey):
                     jnp.where(held2 & anyrel, 0, c["p_retr"][f])),
                 n_sacked=c["n_sacked"].at[c["f_snd"][f]].add(n2))
         # SACK-driven gap resend (rdma._maybe_gap_resend)
-        do_gap = sacknz & ~((c["now"] - c["f_last_gap"][f]) < NAK_HOLDOFF)
+        do_gap = sacknz & ~((c["now"] - c["f_last_gap"][f])
+                            < RdmaNode.NAK_HOLDOFF)
         bl = (jnp.int32(32) - lax.clz(_u32(sack)).astype(jnp.int32))
         hi = (ap + bl) & MASK
         offg = (psn_row - ap) & MASK
@@ -918,7 +925,7 @@ def make_epoch_fn(skey: ShapeKey):
         return lax.fori_loop(0, PC, disp_body, c)
 
     def _on_nak(c, f, ap):
-        skip = (c["now"] - c["f_last_nak"][f]) < NAK_HOLDOFF
+        skip = (c["now"] - c["f_last_nak"][f]) < RdmaNode.NAK_HOLDOFF
 
         def doit(c):
             c = _up(c, f_last_nak=c["f_last_nak"].at[f].set(c["now"]),
@@ -1045,7 +1052,8 @@ def make_epoch_fn(skey: ShapeKey):
                             f_last_cnp_w=c["f_last_cnp_w"].at[f].set(1),
                             n_cnptx=c["n_cnptx"].at[dst].add(1))
                         return _send_ctrl(c, f, I32(3), I32(0), I32(0))
-                    hold = (c["now"] - c["f_last_cnp"][f]) < CNP_HOLDOFF
+                    hold = ((c["now"] - c["f_last_cnp"][f])
+                            < RdmaNode.CNP_HOLDOFF)
                     return lax.cond(hold, lambda c: c, fire, c)
                 return lax.cond((fidx >= 0) & (ecn_f[f] > 0),
                                 do, lambda c: c, c)
@@ -1067,7 +1075,8 @@ def make_epoch_fn(skey: ShapeKey):
 
     # ---- one network tick (netsim.tick + rdma.step_network) -----------
     def _wire_due_perm(c, due):
-        """Pop order of the wire heap: (arrival, seq) lexicographic."""
+        """Pop order of the wire heap: (arrival, seq) lexicographic, in
+        ``WIRE_SORTS_PER_TICK`` sorts."""
         perm1 = jnp.argsort(jnp.where(due, c["w_seq"], BIG))
         key2 = jnp.where(due, c["w_arr"], BIG)[perm1]
         return perm1[jnp.argsort(key2, stable=True)]
@@ -1201,22 +1210,19 @@ def make_epoch_fn(skey: ShapeKey):
                 & (c["idle"] < c["idle_done"])
                 & (c["steps"] < c["max_ticks"]))
 
-    def epoch(vec):
-        c = layout.unpack_jnp(vec)
-        c = lax.while_loop(_cond, _tick, c)
-        return layout.concat(c)
+    def fused_epoch(vec):
+        with jax.named_scope("fused_epoch"):
+            c = layout.unpack_jnp(vec)
+            c = lax.while_loop(_cond, _tick, c)
+            return layout.concat(c)
 
-    return jax.jit(epoch, donate_argnums=(0,))
+    # the device trace names the program jit_fused_epoch
+    return jax.jit(fused_epoch, donate_argnums=(0,))
 
 
 # ---------------------------------------------------------------------------
 # Unpacking: blob -> live Python simulation
 # ---------------------------------------------------------------------------
-
-import collections
-import heapq
-
-from repro.core.retransmit import _Slot
 
 _RX_NAMES = ("rx_epsn", "rx_msn", "rx_bytes", "rx_cur", "rx_cred",
              "rx_rkey", "rx_rxbit", "rx_srf", "rx_acc", "rx_dup",
@@ -1234,9 +1240,11 @@ def _rebuild_pkt(fl: _Flow, kind: int, pidx: int, ap: int,
     return pk.make_cnp(fl.sq, src_ip=fl.rcv.node_id, path_id=-1)
 
 
-def _apply(world: _World, out: np.ndarray, nodes) -> None:
+def _apply(world: _World, out: np.ndarray, nodes) -> Tuple[int, int]:
     """Write the epoch's final blob back into the Python objects,
-    reproducing exactly the state the per-tick oracle would have."""
+    reproducing exactly the state the per-tick oracle would have.
+    Returns the DMA writes replayed into receive buffers and their
+    bytes."""
     lay, flows, skey = world.layout, world.flows, world.skey
     g = lambda name: lay.get(out, name)               # noqa: E731
     g0 = lambda name: lay.get(world.vec0, name)       # noqa: E731
@@ -1253,6 +1261,7 @@ def _apply(world: _World, out: np.ndarray, nodes) -> None:
         for row in np.nonzero(acc[fl.idx])[0]:
             recs.append((int(aseq[fl.idx, row]), fl.idx, int(row)))
     recs.sort()
+    dma_bytes = 0
     for _s, fi, row in recs:
         fl = world.flows[fi]
         t = fl.plan[row]
@@ -1260,6 +1269,7 @@ def _apply(world: _World, out: np.ndarray, nodes) -> None:
         buf = fl.rcv._buffer_for(fl.rq)
         if ln:
             buf[a:a + ln] = t.payload[:ln]
+            dma_bytes += ln
         if fl.snd._sr:
             fl.rcv._sr_note_progress(fl.rq, a, ln)
 
@@ -1415,12 +1425,14 @@ def _apply(world: _World, out: np.ndarray, nodes) -> None:
             lk.sent += int(g("l_sent_d")[li])
             lk.dropped += int(g("l_drop_d")[li])
             lk._ctick, lk._cidx = now, int(g("l_cidx")[li])
+    return len(recs), dma_bytes
 
 
 def run_fused_epoch(nodes, max_ticks: int = 100_000, idle_done: int = 8,
                     watermarks: Optional[Dict[Tuple[int, int], int]] = None
                     ) -> Optional[Dict[str, int]]:
-    """Pack, run one fused epoch on device, unpack.
+    """Pack, run one fused epoch on device, unpack: the three phases are
+    the spans ``balboa.fused.pack``, ``.epoch`` and ``.unpack``.
 
     Returns None when the world is not fusable or the in-graph twin hit
     a case it does not model (retry exhaustion, rkey protection error,
@@ -1433,22 +1445,34 @@ def run_fused_epoch(nodes, max_ticks: int = 100_000, idle_done: int = 8,
     dict carries ``steps``, ``wm_hit``, ``idle_exit`` and ``ticks`` (the
     ``rdma.run_network`` return-value convention).
     """
-    world = try_pack(nodes, max_ticks, idle_done, watermarks)
+    with telemetry.span("balboa.fused.pack") as sp:
+        world = try_pack(nodes, max_ticks, idle_done, watermarks)
+        if world is not None:
+            carry = jnp.asarray(world.vec0)
+            rows = sum(len(fl.plan) for fl in world.flows)
+            sp.set_metadata(flows=world.skey.F, plan_rows=rows,
+                            wire_slots=world.skey.WCAP)
     out = None
     if world is not None:
-        out = np.asarray(make_epoch_fn(world.skey)(jnp.asarray(world.vec0)))
-        if world.layout.get(out, "abort"):
+        lay, epochs = world.layout, world.net.epochs
+        with telemetry.span("balboa.fused.epoch") as sp:
+            out = np.asarray(make_epoch_fn(world.skey)(carry))
+            sp.set_metadata(steps=lay.get(out, "steps"))
+        epochs.carry_bytes += world.vec0.nbytes + out.nbytes
+        if lay.get(out, "abort"):
+            epochs.aborted += 1
             out = None
     if out is None:
         if nodes:
             nodes[0].net.epochs.unfused += 1
         return None
-    lay = world.layout
     steps = lay.get(out, "steps")
     idle_exit = lay.get(out, "idle") >= idle_done
-    _apply(world, out, nodes)
-    world.net.epochs.fused += 1
-    world.net.epochs.fused_ticks += steps
+    with telemetry.span("balboa.fused.unpack") as sp:
+        dmas, nbytes = _apply(world, out, nodes)
+        sp.set_metadata(dmas=dmas, bytes=nbytes)
+    epochs.fused += 1
+    epochs.fused_ticks += steps
     return {"steps": steps, "wm_hit": bool(lay.get(out, "wm_hit")),
             "idle_exit": idle_exit,
             "ticks": (steps - 1) if idle_exit else max_ticks}

@@ -71,6 +71,8 @@ class EpochStats:
     fused: int = 0               # epochs run inside the jitted loop
     fused_ticks: int = 0         # ticks those epochs covered
     unfused: int = 0             # refused attempts -> one per-tick step
+    aborted: int = 0             # of ``unfused``: packed, aborted in-graph
+    carry_bytes: int = 0         # carry put on the device and read back
 
     def snapshot(self) -> dict:
         return dataclasses.asdict(self)
@@ -1087,18 +1089,53 @@ class IncastResult:
     payloads: List[np.ndarray]        # what sender i wrote (QPN i+1 at rx)
 
 
-def incast_scenario(n_senders: int, *, message_bytes: int = 65536,
-                    fabric_cfg: Optional[FabricConfig] = None,
-                    rx_credits: int = 64, fc_window: int = 16,
-                    max_ticks: int = 300_000,
-                    engine: str = "batched",
-                    congestion_control: str = "ack_clocked",
-                    recorder=None,
-                    epoch_mode: Optional[str] = None) -> IncastResult:
-    """The canonical congestion scenario: ``n_senders`` nodes RDMA-WRITE
-    simultaneously into one receiver through a shallow-buffered switch
-    port.  Runs until the fabric drains — callers assert delivery and
-    inspect drop/retransmit stats.
+@dataclasses.dataclass
+class IncastWorld:
+    """A standing N:1 incast, as a served aggregator runs it: the
+    receiver on port 0 keeps one connected QP per sender and takes round
+    after round on them.  A round is one WRITE of one message per sender
+    (``post_round``), then the network driven until quiescent (``run``);
+    PSNs, credits, flow-control ledgers and the receive buffers carry
+    over from one round to the next."""
+    receiver: object                  # RdmaNode (port 0, the hot port)
+    senders: List[object]             # RdmaNode per sender (port i + 1)
+    fabric: SwitchedFabric
+    qps: List[int]                    # sender i's QPN
+    rqps: List[int]                   # the receiver's QPN for sender i
+    buffers: List[np.ndarray]         # the receiver's buffer for sender i
+
+    @property
+    def nodes(self) -> List[object]:
+        return [self.receiver] + self.senders
+
+    def post_round(self, payloads: Sequence[np.ndarray]) -> None:
+        """Post one WRITE of ``payloads[i]`` at offset 0 of sender i's
+        remote buffer, on its standing QP."""
+        for s, qpn, data in zip(self.senders, self.qps, payloads):
+            s.rdma_write(qpn, data)
+
+    def run(self, max_ticks: int = 300_000,
+            epoch_mode: Optional[str] = None) -> int:
+        """Drive the network until quiescent (``rdma.run_network``);
+        returns the ticks elapsed."""
+        from repro.core.rdma import run_network
+        return run_network(self.nodes, max_ticks=max_ticks,
+                           epoch_mode=epoch_mode)
+
+    def completions(self) -> List[int]:
+        """Messages the receiver has completed on each sender's QP."""
+        return [self.receiver.check_completed(q) for q in self.rqps]
+
+
+def incast_world(n_senders: int, *, message_bytes: int = 65536,
+                 fabric_cfg: Optional[FabricConfig] = None,
+                 rx_credits: int = 64, fc_window: int = 16,
+                 n_qps: int = 500, engine: str = "batched",
+                 congestion_control: str = "ack_clocked",
+                 recorder=None) -> IncastWorld:
+    """Build the standing incast of ``incast_scenario``: ``n_senders``
+    nodes, each with one QP connected to the receiver and a registered
+    buffer of ``message_bytes`` on both sides; nothing posted yet.
 
     ``congestion_control="dcqcn"`` arms the full ECN loop: the default
     fabric config then CE-marks above Kmin (unless an explicit
@@ -1106,7 +1143,7 @@ def incast_scenario(n_senders: int, *, message_bytes: int = 65536,
     reaction point, so drop-tail losses give way to rate convergence.
     """
     from repro.core.flow_control import DcqcnConfig     # cycle-free import
-    from repro.core.rdma import RdmaNode, run_network
+    from repro.core.rdma import RdmaNode
 
     if fabric_cfg is not None:
         cfg = fabric_cfg
@@ -1121,26 +1158,47 @@ def incast_scenario(n_senders: int, *, message_bytes: int = 65536,
     # first-RTT burst at line rate would only be drop-tail carnage
     line = float(_per_port(cfg.port_bandwidth, n_senders + 1)[0])
     dcqcn = DcqcnConfig(line_rate=line, initial_rate=line / 4)
-    recv = RdmaNode(0, fabric, rx_credits=rx_credits, engine=engine)
-    senders = [RdmaNode(i + 1, fabric, fc_window=fc_window, engine=engine,
+    recv = RdmaNode(0, fabric, n_qps=n_qps, rx_credits=rx_credits,
+                    engine=engine)
+    senders = [RdmaNode(i + 1, fabric, n_qps=n_qps, fc_window=fc_window,
+                        engine=engine,
                         congestion_control=congestion_control, dcqcn=dcqcn)
                for i in range(n_senders)]
     if recorder is not None:
         fabric.attach_recorder(recorder)
         for n in [recv] + senders:
             n.attach_recorder(recorder)
+    qps = [s.init_rdma(message_bytes, recv)[0] for s in senders]
+    rqps = [s.remote_qpn(q) for s, q in zip(senders, qps)]
+    return IncastWorld(receiver=recv, senders=senders, fabric=fabric,
+                       qps=qps, rqps=rqps,
+                       buffers=[recv._buffer_for(q) for q in rqps])
+
+
+def incast_scenario(n_senders: int, *, message_bytes: int = 65536,
+                    fabric_cfg: Optional[FabricConfig] = None,
+                    rx_credits: int = 64, fc_window: int = 16,
+                    max_ticks: int = 300_000,
+                    engine: str = "batched",
+                    congestion_control: str = "ack_clocked",
+                    recorder=None,
+                    epoch_mode: Optional[str] = None) -> IncastResult:
+    """The canonical congestion scenario: ``n_senders`` nodes RDMA-WRITE
+    simultaneously into one receiver through a shallow-buffered switch
+    port.  Runs one round of ``incast_world`` until the fabric drains —
+    callers assert delivery and inspect drop/retransmit stats."""
+    world = incast_world(n_senders, message_bytes=message_bytes,
+                         fabric_cfg=fabric_cfg, rx_credits=rx_credits,
+                         fc_window=fc_window, engine=engine,
+                         congestion_control=congestion_control,
+                         recorder=recorder)
     rng = np.random.default_rng(13)
-    work = []
-    for s in senders:
-        qpn, _, _ = s.init_rdma(message_bytes, recv)
-        data = rng.integers(0, 256, message_bytes, dtype=np.uint8)
-        work.append((s, qpn, data))
-    for s, qpn, data in work:
-        s.rdma_write(qpn, data)
-    ticks = run_network([recv] + senders, max_ticks=max_ticks,
-                        epoch_mode=epoch_mode)
-    return IncastResult(receiver=recv, senders=senders, fabric=fabric,
-                        ticks=ticks, payloads=[d for _, _, d in work])
+    payloads = [rng.integers(0, 256, message_bytes, dtype=np.uint8)
+                for _ in world.senders]
+    world.post_round(payloads)
+    ticks = world.run(max_ticks=max_ticks, epoch_mode=epoch_mode)
+    return IncastResult(receiver=world.receiver, senders=world.senders,
+                        fabric=world.fabric, ticks=ticks, payloads=payloads)
 
 
 def clos_incast_scenario(n_senders: int, *, message_bytes: int = 65536,

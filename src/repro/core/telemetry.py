@@ -242,6 +242,9 @@ SPANS = (
     "balboa.rx.sync",     # on_packets: RX engine and chain, read back
     "balboa.rx.land",     # on_packets: landing, ACKs, completions
     "balboa.tx",          # one message or READ request packetised, sent
+    "balboa.fused.pack",  # run_fused_epoch: try_pack and the carry's put
+    "balboa.fused.epoch",  # run_fused_epoch: dispatch, the one read-back
+    "balboa.fused.unpack",  # run_fused_epoch: _apply, DMAs replayed
 )
 
 

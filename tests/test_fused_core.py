@@ -25,9 +25,13 @@ boundary), and queue ``on_event`` hooks (packing bails when one is
 installed).
 """
 import copy
+import hashlib
+import re
 import sys
 
+import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from _hyp import given, settings, st
 
@@ -35,7 +39,8 @@ from repro.core import fused
 from repro.core import packet as pk
 from repro.core import pipeline as pipe
 from repro.core.netsim import (FabricConfig, LinkConfig, Network,
-                               SwitchedFabric)
+                               SwitchedFabric, incast_scenario,
+                               incast_world)
 from repro.core.rdma import RdmaNode, run_network, step_network
 
 MTU = 256                     # small MTU => multi-packet, multi-chunk plans
@@ -378,6 +383,65 @@ def test_engine_counter_contract_rides_the_carry():
                 np.asarray(getattr(nd_o.rx_tables, f)),
                 np.asarray(getattr(nd_f.rx_tables, f)), err_msg=f)
         assert vars(nd_o.stats) == vars(nd_f.stats)
+
+
+def test_standing_incast_rounds_stay_fused_and_match_oracle():
+    """A served incast keeps its QPs and takes round after round: PSNs
+    continue, credits and flow-control ledgers carry over, receive
+    buffers are written again.  Three rounds of a 4:1 incast at 64 KiB
+    over 16-QP nodes stay fused, leave the world bit-identical to the
+    same rounds stepped per tick, and land every byte of every round."""
+    worlds = {m: incast_world(4, message_bytes=1 << 16, n_qps=16)
+              for m in ("tick", "fused")}
+    for r in range(3):
+        rng = np.random.default_rng([29, r])
+        pay = [rng.integers(0, 256, 1 << 16, dtype=np.uint8)
+               for _ in range(4)]
+        ticks = {}
+        for mode, w in worlds.items():
+            w.post_round(pay)
+            ticks[mode] = w.run(epoch_mode=mode)
+            assert w.completions() == [2 * (r + 1)] * 4
+            for buf, p in zip(w.buffers, pay):
+                np.testing.assert_array_equal(buf, p)
+        assert ticks["tick"] == ticks["fused"]
+        d = diff(snap(worlds["tick"].nodes), snap(worlds["fused"].nodes))
+        assert not d, f"round {r} diverged:\n  " + "\n  ".join(d[:40])
+    ep = worlds["fused"].fabric.epochs
+    assert ep.fused == 3 and ep.unfused == 0 and ep.aborted == 0
+    assert ep.carry_bytes > 0 and ep.carry_bytes % 3 == 0
+    assert worlds["tick"].fabric.epochs.carry_bytes == 0
+
+
+def test_star_epoch_sorts_per_tick():
+    """The device trace counts the star epoch's ticks by its sorts: the
+    compiled program holds ``WIRE_SORTS_PER_TICK`` of them, all in the
+    tick body (the while loop's body is lowered once)."""
+    w = incast_world(2, message_bytes=1 << 12, n_qps=4)
+    w.post_round([np.zeros(1 << 12, np.uint8)] * 2)
+    world = fused.try_pack(w.nodes, 1000, 8, None)
+    assert world is not None
+    hlo = fused.make_epoch_fn(world.skey).lower(
+        jnp.asarray(world.vec0)).compile().as_text()
+    assert len(re.findall(r"\bsort\(", hlo)) == fused.WIRE_SORTS_PER_TICK
+
+
+@pytest.mark.parametrize("mode", ["tick", "fused"])
+def test_incast_scenario_unchanged(mode):
+    """``incast_scenario`` is one round of the standing incast world and
+    returns what it did before that world existed: ticks, tail drops,
+    retransmissions and the payloads (digest) of a 4:1 incast at 64 KiB."""
+    res = incast_scenario(4, message_bytes=1 << 16, epoch_mode=mode)
+    assert (res.ticks, res.fabric.total_tail_dropped,
+            sum(s.stats.retransmissions for s in res.senders)) == (81, 32, 32)
+    digest = hashlib.sha256(b"".join(p.tobytes() for p in res.payloads))
+    assert digest.hexdigest()[:16] == "dcdc55bb520922d0"
+    for s, p in zip(res.senders, res.payloads):
+        q = next(iter(s._peer))
+        np.testing.assert_array_equal(
+            res.receiver._buffer_for(s.remote_qpn(q)), p)
+    assert res.fabric.epochs.unfused == 0
+    assert res.fabric.epochs.fused == (mode == "fused")
 
 
 if __name__ == "__main__":

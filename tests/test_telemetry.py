@@ -616,21 +616,19 @@ def test_read_back_keeps_every_bit(rows):
     assert node.host_stats.d2h_reads == 2
 
 
-def test_profiled_ticks_hold_nested_spans_with_stats(tmp_path):
-    """Under a CPU ``jax.profiler`` session a few ticks of a two-node
-    flow record every declared span with its stats; receive spans run
-    in order and do not overlap; a message sent from ACK handling nests
-    in ``balboa.rx.ctrl``."""
+FUSED_SPANS = {"balboa.fused.pack", "balboa.fused.epoch",
+               "balboa.fused.unpack"}
+
+
+def _profiled_spans(tmp_path, body):
+    """Run ``body`` under a CPU ``jax.profiler`` session; return its
+    ``balboa.*`` host events as (name, start ns, end ns, stats)."""
     import glob
     import jax
     from jax.profiler import ProfileData
-    from repro.core.rdma import step_network
-    nodes = _two_node_flow(_RecordingChain(), loss=0.0)
-    step_network(nodes)                          # compile outside
     jax.profiler.start_trace(str(tmp_path))
     try:
-        for _ in range(40):
-            step_network(nodes)
+        body()
     finally:
         jax.profiler.stop_trace()
     [path] = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
@@ -640,7 +638,23 @@ def test_profiled_ticks_hold_nested_spans_with_stats(tmp_path):
             spans += [(e.name, e.start_ns, e.start_ns + e.duration_ns,
                        dict(e.stats)) for e in line.events
                       if e.name.startswith("balboa.")]
-    assert {n for n, *_ in spans} == set(tm.SPANS)
+    return spans
+
+
+def test_profiled_ticks_hold_nested_spans_with_stats(tmp_path):
+    """Under a CPU ``jax.profiler`` session a few ticks of a two-node
+    flow record every declared span of the per-tick path with its stats;
+    receive spans run in order and do not overlap; a message sent from
+    ACK handling nests in ``balboa.rx.ctrl``."""
+    from repro.core.rdma import step_network
+    nodes = _two_node_flow(_RecordingChain(), loss=0.0)
+    step_network(nodes)                          # compile outside
+
+    def ticks():
+        for _ in range(40):
+            step_network(nodes)
+    spans = _profiled_spans(tmp_path, ticks)
+    assert {n for n, *_ in spans} == set(tm.SPANS) - FUSED_SPANS
     by = {}
     for n, s, e, st_ in spans:
         by.setdefault(n, []).append((s, e, st_))
@@ -665,3 +679,29 @@ def test_profiled_ticks_hold_nested_spans_with_stats(tmp_path):
     # nothing is sent from inside the fabric's tick
     assert not any(inside(t, f) for t in by["balboa.tx"]
                    for f in by["balboa.fabric"])
+
+
+def test_profiled_fused_epoch_holds_its_spans_with_stats(tmp_path):
+    """One fused run of a 2:1 incast records ``balboa.fused.pack``,
+    ``.epoch`` and ``.unpack`` in that order, one after the other, with
+    their stats: the flows, plan rows and wire slots packed, the ticks
+    run, and the DMA writes and bytes replayed into receive buffers."""
+    from repro.core.netsim import incast_world
+    w = incast_world(2, message_bytes=8192, n_qps=8)
+    w.post_round([np.full(8192, 7, np.uint8)] * 2)
+    w.run(epoch_mode="fused")                    # compile outside
+    w.post_round([np.full(8192, 9, np.uint8)] * 2)
+    spans = _profiled_spans(tmp_path, lambda: w.run(epoch_mode="fused"))
+    by = {n: (s, e, st_) for n, s, e, st_ in spans if n in FUSED_SPANS}
+    assert set(by) == FUSED_SPANS
+    assert len([n for n, *_ in spans if n in FUSED_SPANS]) == 3
+    pack, epoch, unpack = (by[f"balboa.fused.{k}"]
+                           for k in ("pack", "epoch", "unpack"))
+    assert pack[1] <= epoch[0] and epoch[1] <= unpack[0]
+    # two directed flows a connection; 8 KiB is 2 MTU packets a sender;
+    # wire slots: the bucket over the packets in flight, twice the flows'
+    # windows (16 a sender, 64 at the receiver), twice the flows, and 16
+    assert pack[2] == {"flows": 4, "plan_rows": 4, "wire_slots": 512}
+    assert epoch[2]["steps"] == w.fabric.epochs.fused_ticks // 2
+    assert unpack[2] == {"dmas": 4, "bytes": 2 * 8192}
+    assert all((b == 9).all() for b in w.buffers)
