@@ -34,10 +34,15 @@ Design
   per-QP counter columns (``pipeline.COUNTER_FIELDS``) ride the blob
   and are harvested exactly once, at the epoch boundary.
 
-The in-graph tick mirrors the oracle *sequentially* (nested
-``fori_loop``s in exact oracle event order) — bit-identity is the gate;
-the win is host<->device traffic, which drops from O(ticks) to O(1)
-per epoch (see BENCH_sync_census.json before/after).
+The in-graph tick still mirrors the oracle *sequentially*, in exact
+oracle event order — bit-identity is the gate — but its loops run over
+live work only: the timer scan, the wire pop and the ACK/NAK resend and
+dispatch loops first build their mask or count with one vector op, then
+loop over the live indices alone, in the oracle's order (the carry's
+``loop_trips`` counts those trips).  The win is host<->device traffic,
+which drops from O(ticks) to O(1) per epoch (see BENCH_sync_census.json
+before/after), and a tick whose cost follows the packets and timers in
+play, not the slots allocated for them.
 """
 from __future__ import annotations
 
@@ -198,6 +203,7 @@ def _layout_for(skey: ShapeKey) -> _Layout:
         # -- globals ----------------------------------------------------
         ("now", S), ("steps", S), ("idle", S), ("abort", S),
         ("acc_ctr", S), ("wm_hit", S), ("max_ticks", S), ("idle_done", S),
+        ("loop_trips", S),
         # -- flows ------------------------------------------------------
         ("f_snd", (F,)), ("f_sq", (F,)), ("f_rcv", (F,)), ("f_rq", (F,)),
         ("f_sr", (F,)), ("f_window", (F,)), ("f_gap_lag", (F,)),
@@ -720,15 +726,31 @@ def _up(c, **kw):
 def make_epoch_fn(skey: ShapeKey):
     """Build (and cache, per shape key) the jitted blob -> blob epoch
     function.  The in-graph tick mirrors the Python oracle *in exact
-    event order* via nested ``fori_loop``s; the payoff is that the
-    entire epoch is ONE device program with ONE donated input and ONE
-    output — host<->device traffic no longer scales with ticks."""
+    event order*, looping over live rows and wire slots only
+    (``_live_loop``); the payoff is that the entire epoch is ONE device
+    program with ONE donated input and ONE output — host<->device
+    traffic no longer scales with ticks."""
     layout = _cached_layout(skey)
     star = skey.mode == "star"
     N, F, PC, CC = skey.N, skey.F, skey.PC, skey.CC
-    WCAP, RCAP, G = skey.WCAP, skey.RCAP, skey.G
+    RCAP = skey.RCAP
     ARPC = jnp.arange(PC, dtype=jnp.int32)
     I32 = partial(jnp.asarray, dtype=jnp.int32)
+
+    def _live_loop(c, live, body):
+        """``body(i, c)`` for each live index ``i``, ascending: the
+        oracle's visiting order with the idle visits left out.  ``live``
+        is a flat mask (its set indices, compacted without a sort) or a
+        count ``n`` (indices ``0 .. n-1``).  The trips add to
+        ``loop_trips``."""
+        if live.ndim:
+            idx = jnp.nonzero(live, size=live.size, fill_value=0)[0]
+            n = jnp.sum(live.astype(jnp.int32))
+            step = lambda j, c: body(idx[j], c)        # noqa: E731
+        else:
+            n, step = live, body
+        c = _up(c, loop_trips=c["loop_trips"] + n)
+        return lax.fori_loop(0, n, step, c)
 
     # ---- wire / ring primitives ---------------------------------------
     def _wire_push(c, arr, loc, seqv, f, kind, pidx, ap, sack):
@@ -883,12 +905,7 @@ def make_epoch_fn(skey: ShapeKey):
                           f_last_gap=c["f_last_gap"].at[f].set(c["now"]),
                           f_last_gap_w=c["f_last_gap_w"].at[f].set(1)),
             lambda c: c, c)
-        c = lax.fori_loop(
-            0, PC,
-            lambda row, c: lax.cond(gmask[row],
-                                    lambda c: _bump_send(c, f, row),
-                                    lambda c: c, c),
-            c)
+        c = _live_loop(c, gmask, lambda row, c: _bump_send(c, f, row))
         # ACK-clocked flow control (flow_control.ack + _drain + dispatch)
         rel = jnp.maximum(n1 + n2, 1)
         out0 = jnp.maximum(0, c["f_out"][f] - rel)
@@ -913,16 +930,14 @@ def make_epoch_fn(skey: ShapeKey):
                 f_budget=c["f_budget"].at[f].set(bud1),
                 f_tpassed_d=c["f_tpassed_d"].at[f].add(taken))
 
-        def disp_body(k, c):
-            def fire(c):
-                row = nxt0 + k
-                c = _up(c, p_held=c["p_held"].at[f, row].set(1),
-                        p_retr=c["p_retr"].at[f, row].set(0),
-                        p_dl=c["p_dl"].at[f, row].set(
-                            c["now"] + c["f_timeout"][f]))
-                return _send_data(c, f, row)
-            return lax.cond(k < tot, fire, lambda c: c, c)
-        return lax.fori_loop(0, PC, disp_body, c)
+        def fire(k, c):
+            row = nxt0 + k
+            c = _up(c, p_held=c["p_held"].at[f, row].set(1),
+                    p_retr=c["p_retr"].at[f, row].set(0),
+                    p_dl=c["p_dl"].at[f, row].set(
+                        c["now"] + c["f_timeout"][f]))
+            return _send_data(c, f, row)
+        return _live_loop(c, tot, fire)
 
     def _on_nak(c, f, ap):
         skip = (c["now"] - c["f_last_nak"][f]) < RdmaNode.NAK_HOLDOFF
@@ -934,12 +949,7 @@ def make_epoch_fn(skey: ShapeKey):
             psn_row = (c["f_base"][f] + ARPC) & MASK
             mask = (c["p_held"][f] > 0) & (
                 ((psn_row - expected) & MASK) <= HALF)
-            return lax.fori_loop(
-                0, PC,
-                lambda row, c: lax.cond(mask[row],
-                                        lambda c: _bump_send(c, f, row),
-                                        lambda c: c, c),
-                c)
+            return _live_loop(c, mask, lambda row, c: _bump_send(c, f, row))
         return lax.cond(skip, lambda c: c, doit, c)
 
     def _on_cnp(c, f, _ap):
@@ -1091,15 +1101,13 @@ def make_epoch_fn(skey: ShapeKey):
             perm = _wire_due_perm(c, due)
             n_due = jnp.sum(due.astype(jnp.int32))
 
-            def pop_body(i, c):
-                def do(c):
-                    s = perm[i]
-                    c = _up(c, w_valid=c["w_valid"].at[s].set(0))
-                    return _ring_enq(c, c["w_dst"][s], c["w_flow"][s],
-                                     c["w_kind"][s], c["w_pidx"][s],
-                                     c["w_ap"][s], c["w_sack"][s])
-                return lax.cond(i < n_due, do, lambda c: c, c)
-            c = lax.fori_loop(0, WCAP, pop_body, c)
+            def pop(i, c):
+                s = perm[i]
+                c = _up(c, w_valid=c["w_valid"].at[s].set(0))
+                return _ring_enq(c, c["w_dst"][s], c["w_flow"][s],
+                                 c["w_kind"][s], c["w_pidx"][s],
+                                 c["w_ap"][s], c["w_sack"][s])
+            c = _live_loop(c, n_due, pop)
             # phase 2: drain each port, feed the batch to its node
             for port in range(skey.P):
                 B = skey.DEL[port]
@@ -1180,17 +1188,14 @@ def make_epoch_fn(skey: ShapeKey):
                 c = _process_batch(c, li, skey.LDST[li],
                                    (bv, bf, bp_, bk, ba, bs, z), B)
 
-        # phase 3: retransmission timers (rdma.tick, node x QPN order)
-        def timer_flow(k, c):
-            f = c["t_order"][k]
-
-            def row_body(row, c):
-                due = ((c["p_held"][f, row] > 0)
-                       & (c["now"] >= c["p_dl"][f, row]))
-                return lax.cond(due, lambda c: _bump_send(c, f, row),
-                                lambda c: c, c)
-            return lax.fori_loop(0, PC, row_body, c)
-        c = lax.fori_loop(0, F, timer_flow, c)
+        # phase 3: retransmission timers (rdma.tick, node x QPN order).
+        # A bump changes only its own row's retries and deadline, never
+        # another row's, so the due mask taken up front is the oracle's
+        # test at each visit; flat index k * PC + row, f = t_order[k].
+        order = c["t_order"]
+        due = (c["p_held"][order] > 0) & (c["now"] >= c["p_dl"][order])
+        c = _live_loop(c, due.reshape(-1),
+                       lambda i, c: _bump_send(c, order[i // PC], i % PC))
 
         # phase 4: idle / watermark accounting (rdma.run_network)
         pending = (jnp.any(c["w_valid"] > 0) | jnp.any(c["p_held"] > 0)
@@ -1457,7 +1462,8 @@ def run_fused_epoch(nodes, max_ticks: int = 100_000, idle_done: int = 8,
         lay, epochs = world.layout, world.net.epochs
         with telemetry.span("balboa.fused.epoch") as sp:
             out = np.asarray(make_epoch_fn(world.skey)(carry))
-            sp.set_metadata(steps=lay.get(out, "steps"))
+            sp.set_metadata(steps=lay.get(out, "steps"),
+                            trips=lay.get(out, "loop_trips"))
         epochs.carry_bytes += world.vec0.nbytes + out.nbytes
         if lay.get(out, "abort"):
             epochs.aborted += 1
@@ -1473,6 +1479,7 @@ def run_fused_epoch(nodes, max_ticks: int = 100_000, idle_done: int = 8,
         sp.set_metadata(dmas=dmas, bytes=nbytes)
     epochs.fused += 1
     epochs.fused_ticks += steps
+    epochs.loop_trips += lay.get(out, "loop_trips")
     return {"steps": steps, "wm_hit": bool(lay.get(out, "wm_hit")),
             "idle_exit": idle_exit,
             "ticks": (steps - 1) if idle_exit else max_ticks}

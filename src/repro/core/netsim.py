@@ -70,6 +70,7 @@ class EpochStats:
     0``."""
     fused: int = 0               # epochs run inside the jitted loop
     fused_ticks: int = 0         # ticks those epochs covered
+    loop_trips: int = 0          # trips of their live-work loops
     unfused: int = 0             # refused attempts -> one per-tick step
     aborted: int = 0             # of ``unfused``: packed, aborted in-graph
     carry_bytes: int = 0         # carry put on the device and read back

@@ -142,13 +142,29 @@ def diff(a, b, path=""):
     return out
 
 
+def live_work(nodes):
+    """The oracle's own counts of what the fused tick's live-work loops
+    visit: packets popped off the star fabric's wire into an egress ring
+    (enqueued or tail-dropped), PSNs handed out (first sends) and
+    retransmissions (timer, NAK and gap bumps).  A trip of those loops
+    is one of these, so their sum is ``EpochStats.loop_trips``."""
+    net = nodes[0].net
+    pops = (sum(st_.enqueued + st_.tail_dropped for st_ in net.port_stats)
+            if isinstance(net, SwitchedFabric) else 0)
+    psns = sum(int(v) for n in nodes for v in n.qp.tables.npsn)
+    retx = sum(n.stats.retransmissions for n in nodes)
+    return np.array([pops, psns, retx])
+
+
 def assert_fused_matches_oracle(nodes, max_ticks=100_000, idle_done=8,
                                 watermarks=None, expect_fused=True):
     """Run one fused epoch on ``nodes`` and the same number of per-tick
-    oracle steps on a deepcopy; assert the two worlds are bit-identical.
+    oracle steps on a deepcopy; assert the two worlds are bit-identical
+    and that the epoch's ``loop_trips`` is the live work the oracle did.
     Returns the fused result dict (or None when ``expect_fused`` is
     False and the world legitimately does not pack)."""
     oracle = copy.deepcopy(nodes)
+    work0, trips0 = live_work(oracle), nodes[0].net.epochs.loop_trips
     res = fused.run_fused_epoch(nodes, max_ticks=max_ticks,
                                 idle_done=idle_done, watermarks=watermarks)
     if res is None:
@@ -160,6 +176,8 @@ def assert_fused_matches_oracle(nodes, max_ticks=100_000, idle_done=8,
     d = diff(snap(oracle), snap(nodes))
     assert not d, "fused epoch diverged from per-tick oracle:\n  " \
         + "\n  ".join(d[:40])
+    res["work"] = live_work(oracle) - work0
+    assert nodes[0].net.epochs.loop_trips - trips0 == res["work"].sum()
     return res
 
 
@@ -169,7 +187,7 @@ def assert_fused_matches_oracle(nodes, max_ticks=100_000, idle_done=8,
 
 def build_star(seed, *, sr=False, loss=0.0, kmax=0, nbytes=2000,
                n_senders=2, bw=3, cap=16, window=16, presteps=0,
-               extra_qps=0):
+               extra_qps=0, timeout=64):
     cfg = FabricConfig(port_bandwidth=bw, port_delay=2,
                        queue_capacity=cap, loss_prob=loss,
                        ecn_kmin=4, ecn_kmax=kmax, seed=seed % 1000,
@@ -179,6 +197,8 @@ def build_star(seed, *, sr=False, loss=0.0, kmax=0, nbytes=2000,
     kw = dict(fc_window=window, rx_mode=mode, n_qps=32, mtu=MTU)
     recv = RdmaNode(0, fab, **kw)
     senders = [RdmaNode(i + 1, fab, **kw) for i in range(n_senders)]
+    for n in [recv] + senders:
+        n.retx.timeout = timeout
     rng = np.random.default_rng(seed)
     for i, s in enumerate(senders):
         for j in range(1 + (extra_qps if i == 0 else 0)):
@@ -194,7 +214,7 @@ def build_star(seed, *, sr=False, loss=0.0, kmax=0, nbytes=2000,
 
 def build_p2p(seed, *, sr=False, loss=0.0, reorder=0.0, jitter=0,
               nbytes=2000, latency=2, bw=0, window=16, presteps=0,
-              n_flows=2):
+              n_flows=2, timeout=64):
     chaos = seed if (loss or reorder or jitter) else None
     cfg = LinkConfig(loss_prob=loss, reorder_prob=reorder,
                      jitter_ticks=jitter, latency_ticks=latency,
@@ -204,6 +224,7 @@ def build_p2p(seed, *, sr=False, loss=0.0, reorder=0.0, jitter=0,
     mode = "selective_repeat" if sr else "go_back_n"
     kw = dict(fc_window=window, rx_mode=mode, n_qps=32, mtu=MTU)
     a, b = RdmaNode(0, net, **kw), RdmaNode(1, net, **kw)
+    a.retx.timeout = b.retx.timeout = timeout
     rng = np.random.default_rng(seed)
     for i in range(n_flows):
         q, _rk, _buf = a.init_rdma(1 << 16, b)
@@ -424,6 +445,65 @@ def test_star_epoch_sorts_per_tick():
     hlo = fused.make_epoch_fn(world.skey).lower(
         jnp.asarray(world.vec0)).compile().as_text()
     assert len(re.findall(r"\bsort\(", hlo)) == fused.WIRE_SORTS_PER_TICK
+
+
+def _deadlines(nodes):
+    return {(n.node_id, q, psn): slot.deadline for n in nodes
+            for q, slots in n.retx.slots.items()
+            for psn, slot in slots.items()}
+
+
+def widest_bump(nodes, steps):
+    """Step ``nodes`` ``steps`` ticks; the most QPs that had held slots
+    bumped (deadline moved) in one tick."""
+    widest = 0
+    for _ in range(steps):
+        before = _deadlines(nodes)
+        step_network(nodes)
+        after = _deadlines(nodes)
+        widest = max(widest, len({k[:2] for k, dl in before.items()
+                                  if after.get(k, dl) != dl}))
+    return widest
+
+
+@pytest.mark.parametrize("sr", [False, True], ids=["gbn", "sr"])
+@pytest.mark.parametrize("fabric", ["star", "p2p"])
+def test_timeout_burst_across_flows_bit_identical(fabric, sr):
+    """Every flow's window posted in the same tick, a retransmission
+    timeout shorter than the round trip and chaos loss: the windows of
+    all flows time out in the same tick, again at each backoff.  The
+    compacted timer scan then decides in which order the flows' rows
+    take wire slots, sequence numbers, loss ranks and retransmission
+    counts, and the world must still match the oracle bit for bit."""
+    if fabric == "star":
+        nodes = build_star(41, sr=sr, loss=0.05, nbytes=1500, n_senders=3,
+                           extra_qps=1, timeout=3)
+    else:
+        nodes = build_p2p(43, sr=sr, loss=0.05, nbytes=1500, n_flows=3,
+                          timeout=3)
+    flows = {k[:2] for k in _deadlines(nodes)}      # QPs holding a window
+    assert len(flows) == (4 if fabric == "star" else 3)
+    twin = copy.deepcopy(nodes)
+    res = assert_fused_matches_oracle(nodes)
+    assert widest_bump(twin, res["steps"]) == len(flows)
+    assert res["work"][2] > 2 * len(flows)
+
+
+def test_loop_trips_counts_live_work():
+    """A 4:1 star incast at 128 KiB: ``EpochStats.loop_trips`` is the
+    oracle's wire pops plus first sends plus retransmissions, each kind
+    present, and a few trips a tick where looping over every slot would
+    take F x PC + WCAP."""
+    w = incast_world(4, message_bytes=1 << 17, n_qps=16)
+    w.post_round([np.full(1 << 17, i, np.uint8) for i in range(4)])
+    world = fused.try_pack(w.nodes, 100_000, 8)
+    res = assert_fused_matches_oracle(w.nodes)
+    pops, firsts, retx = res["work"]
+    assert pops > 0 and firsts > 0 and retx > 0
+    ep = w.fabric.epochs
+    assert ep.loop_trips == pops + firsts + retx
+    sk = world.skey
+    assert ep.loop_trips * 20 < res["steps"] * (sk.F * sk.PC + sk.WCAP)
 
 
 @pytest.mark.parametrize("mode", ["tick", "fused"])

@@ -685,12 +685,14 @@ def test_profiled_fused_epoch_holds_its_spans_with_stats(tmp_path):
     """One fused run of a 2:1 incast records ``balboa.fused.pack``,
     ``.epoch`` and ``.unpack`` in that order, one after the other, with
     their stats: the flows, plan rows and wire slots packed, the ticks
-    run, and the DMA writes and bytes replayed into receive buffers."""
+    run and the trips of their live-work loops, and the DMA writes and
+    bytes replayed into receive buffers."""
     from repro.core.netsim import incast_world
     w = incast_world(2, message_bytes=8192, n_qps=8)
     w.post_round([np.full(8192, 7, np.uint8)] * 2)
     w.run(epoch_mode="fused")                    # compile outside
     w.post_round([np.full(8192, 9, np.uint8)] * 2)
+    trips0 = w.fabric.epochs.loop_trips
     spans = _profiled_spans(tmp_path, lambda: w.run(epoch_mode="fused"))
     by = {n: (s, e, st_) for n, s, e, st_ in spans if n in FUSED_SPANS}
     assert set(by) == FUSED_SPANS
@@ -703,5 +705,7 @@ def test_profiled_fused_epoch_holds_its_spans_with_stats(tmp_path):
     # windows (16 a sender, 64 at the receiver), twice the flows, and 16
     assert pack[2] == {"flows": 4, "plan_rows": 4, "wire_slots": 512}
     assert epoch[2]["steps"] == w.fabric.epochs.fused_ticks // 2
+    # the live-work loops' trips, as EpochStats adds them up
+    assert epoch[2]["trips"] == w.fabric.epochs.loop_trips - trips0 > 0
     assert unpack[2] == {"dmas": 4, "bytes": 2 * 8192}
     assert all((b == 9).all() for b in w.buffers)
