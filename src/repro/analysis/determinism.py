@@ -15,10 +15,10 @@ pins it).  Everything that can silently break that contract is lint:
                         calls and across tests).
 
 Scoping: inside ``src/repro`` each rule applies only where the hazard
-is real (the wall clock is fine in ``launch/``; dict order is fine in a
-pure lookup table).  Paths *outside* ``src/repro`` — e.g. the lint's
-own test fixtures — get every rule, so fixtures can exercise all of
-them without carve-outs.
+is real (the wall clock is fine outside the data plane's ``core/``,
+``kernels/`` and ``data/``; dict order is fine in a pure lookup table).
+Paths *outside* ``src/repro`` — e.g. the lint's own test fixtures — get
+every rule, so fixtures can exercise all of them without carve-outs.
 """
 from __future__ import annotations
 
@@ -28,8 +28,8 @@ from typing import Iterable, List
 
 from repro.analysis.violations import REPO_ROOT, Violation, relpath
 
-# data-plane subtrees where wall-clock reads are forbidden (launch/,
-# train/, benchmarks legitimately measure wall time)
+# data-plane subtrees where wall-clock reads are forbidden (benchmarks/
+# legitimately measure wall time)
 WALL_CLOCK_SCOPE = ("core", "kernels", "data")
 
 # modules where iteration order IS wire/trace order

@@ -4,15 +4,42 @@ features, pairwise dot interaction, top MLP) trained online behind the
 BALBOA ingest path."""
 from __future__ import annotations
 
-from typing import Dict, Tuple
+import math
+from typing import Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.common.config import DLRMConfig
-from repro.models.params import Spec
-from repro.models import params as P
-from repro.parallel.sharding import constrain
+from repro.configs.dlrm import DLRMConfig
+
+
+class Spec(NamedTuple):
+    """One parameter: its shape and initializer."""
+    shape: Tuple[int, ...]
+    init: str = "fan_in"      # fan_in (2-D weights) | normal | zeros
+
+
+def _init_one(spec: Spec, key, param_dtype: str):
+    dtype = jnp.dtype(param_dtype)
+    if spec.init == "zeros":
+        return jnp.zeros(spec.shape, dtype)
+    if spec.init == "normal":
+        std = 0.02
+    elif spec.init == "fan_in":
+        std = 1.0 / math.sqrt(spec.shape[0])
+    else:
+        raise ValueError(f"unknown init {spec.init}")
+    return (std * jax.random.normal(key, spec.shape, jnp.float32)
+            ).astype(dtype)
+
+
+def init(tree, key, param_dtype: str):
+    """Materialize a ``Spec`` tree: one key per leaf, in leaf order."""
+    leaves, treedef = jax.tree.flatten(
+        tree, is_leaf=lambda x: isinstance(x, Spec))
+    keys = jax.random.split(key, len(leaves))
+    vals = [_init_one(s, k, param_dtype) for s, k in zip(leaves, keys)]
+    return jax.tree.unflatten(treedef, vals)
 
 
 class DLRM:
@@ -24,23 +51,23 @@ class DLRM:
         spec: Dict = {"tables": {}}
         for i in range(cfg.n_sparse):
             spec["tables"][f"t{i}"] = Spec(
-                (cfg.embed_rows, cfg.embed_dim), ("vocab", None), "normal")
+                (cfg.embed_rows, cfg.embed_dim), "normal")
         dims = (cfg.n_dense,) + cfg.bottom_mlp
         spec["bottom"] = {
-            f"l{i}": {"w": Spec((dims[i], dims[i + 1]), ("embed", "d_ff")),
-                      "b": Spec((dims[i + 1],), (None,), "zeros")}
+            f"l{i}": {"w": Spec((dims[i], dims[i + 1])),
+                      "b": Spec((dims[i + 1],), "zeros")}
             for i in range(len(dims) - 1)}
         n_f = cfg.n_sparse + 1
         inter_dim = cfg.bottom_mlp[-1] + n_f * (n_f - 1) // 2
         tdims = (inter_dim,) + cfg.top_mlp
         spec["top"] = {
-            f"l{i}": {"w": Spec((tdims[i], tdims[i + 1]), ("embed", "d_ff")),
-                      "b": Spec((tdims[i + 1],), (None,), "zeros")}
+            f"l{i}": {"w": Spec((tdims[i], tdims[i + 1])),
+                      "b": Spec((tdims[i + 1],), "zeros")}
             for i in range(len(tdims) - 1)}
         return spec
 
     def init_params(self, key):
-        return P.init(self.param_spec(), key, self.cfg.param_dtype)
+        return init(self.param_spec(), key, self.cfg.param_dtype)
 
     def forward(self, params, dense: jax.Array, sparse: jax.Array
                 ) -> jax.Array:

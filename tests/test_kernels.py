@@ -175,6 +175,22 @@ def test_fused_chain_tile_entry_matches_oneshot():
         fused_decrypt_dpi_tile(jnp.asarray(pay), rk, params, tile_pkts=8)
 
 
+def test_fused_chain_kernel_matches_two_pass():
+    from repro.kernels.fused_chain import (fused_decrypt_dpi_pallas,
+                                           fused_decrypt_dpi_ref)
+    from repro.kernels.ref import expand_key
+    from repro.kernels.dpi_mlp import init_dpi_params, ternarize
+    rng = np.random.default_rng(0)
+    pay = rng.integers(0, 256, (5, 1024), dtype=np.uint8)
+    rk = expand_key(rng.integers(0, 256, 16, dtype=np.uint8))
+    params = ternarize(init_dpi_params(jax.random.key(0)))
+    p1, s1 = fused_decrypt_dpi_pallas(jnp.asarray(pay), rk, params)
+    p2, s2 = fused_decrypt_dpi_ref(jnp.asarray(pay), rk, params)
+    np.testing.assert_array_equal(np.asarray(p1), np.asarray(p2))
+    np.testing.assert_allclose(np.asarray(s1), np.asarray(s2),
+                               rtol=1e-5, atol=1e-5)
+
+
 # ---------------------------------------------------------------------------
 # DLRM preprocessing
 # ---------------------------------------------------------------------------
