@@ -111,7 +111,7 @@ def _dpi_params():
 def registry() -> List[EntryPoint]:
     import jax.numpy as jnp
     from repro.core import pipeline as pipe
-    from repro.kernels import fused_chain, ops, reduce as red
+    from repro.kernels import fused_chain, ops, preproc as pre, reduce as red
 
     def aes_args():
         import jax.numpy as jnp
@@ -132,6 +132,9 @@ def registry() -> List[EntryPoint]:
         recs = jnp.asarray(rng.integers(0, 1 << 20, (16, 39),
                                         dtype=np.int32))
         return (recs,), {}
+
+    def packets_args():
+        return (_payload(),), {}
 
     def fused_args():
         return (_payload(), _round_keys(), _dpi_params()), {}
@@ -173,6 +176,10 @@ def registry() -> List[EntryPoint]:
         EntryPoint("kernels.preproc[pallas]",
                    lambda r: ops.preproc(r, 13, 100_000, impl="pallas"),
                    preproc_args, site=ops.preproc),
+        EntryPoint("kernels.preproc_packets_pallas",
+                   lambda p: pre.preproc_packets_pallas(p, 13, 40,
+                                                        40_000_000),
+                   packets_args, site=pre.preproc_packets_pallas),
         EntryPoint("kernels.preproc_tile",
                    lambda r: ops.preproc_tile(r, 13, 100_000,
                                               tile_recs=32),

@@ -105,8 +105,16 @@ class DpiService(ParallelPathService):
 class PreprocService(OnPathService):
     """DLRM preprocessing offload (paper §8.1): Neg2Zero -> Log on dense
     features, Modulus on sparse features, at line rate on the stream.
-    Payload layout: int32 little-endian, ``n_dense`` dense then
-    ``n_sparse`` sparse columns per record."""
+
+    Layout contract: each packet row carries ``n_rec = (MTU // 4) //
+    rec_words`` whole records (``rec_words = n_dense + n_sparse`` int32
+    little-endian words, dense columns first) and then a tail.  Word
+    ``w`` below ``n_rec * rec_words`` is column ``w % rec_words`` of a
+    record: float32 bits of ``log1p(max(x, 0))`` below ``n_dense``,
+    ``x mod modulus`` from there on; the tail bytes pass through.  Rows
+    are independent and a word's treatment depends only on its position,
+    so the kernel transforms the packet rows as they arrive, with no
+    record relayout around it."""
     n_dense: int = 13
     n_sparse: int = 26
     modulus: int = 100_000
@@ -115,21 +123,10 @@ class PreprocService(OnPathService):
 
     def __call__(self, payload: jax.Array, plen: jax.Array) -> jax.Array:
         from repro.kernels import preproc as ops
-        fn = ops.preproc_pallas if self.use_pallas else ops.preproc_ref
-        n, mtu = payload.shape
-        rec_words = self.n_dense + self.n_sparse
-        words = mtu // 4
-        n_rec = words // rec_words
-        x = jax.lax.bitcast_convert_type(
-            payload.reshape(n, words, 4), jnp.int32).reshape(n, words)
-        recs = x[:, :n_rec * rec_words].reshape(n * n_rec, rec_words)
-        out = fn(recs, self.n_dense, self.modulus)
-        out_words = jnp.concatenate(
-            [out.reshape(n, n_rec * rec_words),
-             x[:, n_rec * rec_words:]], axis=1)
-        out_bytes = jax.lax.bitcast_convert_type(
-            out_words.reshape(n, words, 1), jnp.uint8).reshape(n, mtu)
-        return out_bytes
+        fn = (ops.preproc_packets_pallas if self.use_pallas
+              else ops.preproc_packets_ref)
+        return fn(payload, self.n_dense, self.n_dense + self.n_sparse,
+                  self.modulus)
 
 
 @dataclasses.dataclass

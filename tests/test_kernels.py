@@ -234,6 +234,39 @@ def test_preproc_semantics():
     assert out[0, 3] == 12345 % 100
 
 
+@pytest.mark.parametrize("cols", [(13, 27), (13, 26), (1, 1), None])
+@settings(max_examples=3, deadline=None)
+@given(rows=st.integers(1, 600), mtu=st.sampled_from([4096, 1024]),
+       modulus=st.sampled_from([7, 1000, 40_000_000]),
+       seed=st.integers(0, 2**31))
+def test_preproc_packets_pallas_matches_record_ref(cols, rows, mtu, modulus,
+                                                   seed):
+    """The packet-row kernel equals ``preproc_ref`` over the records the
+    rows carry, bit for bit, with the tail past the last whole record as
+    sent.  ``cols`` None: (n_dense, n_sparse) drawn from the seed."""
+    from repro.kernels.preproc import preproc_packets_pallas
+    rng = np.random.default_rng(seed)
+    n_dense, n_sparse = cols or (int(rng.integers(1, 17)),
+                                 int(rng.integers(1, 31)))
+    rec_words = n_dense + n_sparse
+    n_rec = (mtu // 4) // rec_words
+    words = rng.integers(-2**31, 2**31, (rows, mtu // 4), dtype=np.int64
+                         ).astype(np.int32)
+    words[0, :2] = [-2**31, 2**31 - 1]          # both ends, dense or not
+    words[-1, n_dense:rec_words] = 2**31 - 1    # the largest ids
+    pay = words.view(np.uint8)
+    got = np.asarray(preproc_packets_pallas(jnp.asarray(pay), n_dense,
+                                            rec_words, modulus))
+    recs = words[:, :n_rec * rec_words].reshape(-1, rec_words)
+    want = np.asarray(ops.preproc(jnp.asarray(recs), n_dense, modulus,
+                                  impl="ref"))
+    got_words = got.view(np.int32)
+    np.testing.assert_array_equal(
+        got_words[:, :n_rec * rec_words].reshape(-1, rec_words), want)
+    tail = 4 * n_rec * rec_words
+    np.testing.assert_array_equal(got[:, tail:], pay[:, tail:])
+
+
 # ---------------------------------------------------------------------------
 # Segmented reduce (collective offload math)
 # ---------------------------------------------------------------------------

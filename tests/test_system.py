@@ -89,9 +89,10 @@ def test_ingest_preprocessed_dlrm_stream():
     n_rec = mtu_records * 4       # 4 full packets
     shard_fn = lambda i: syn.encode_dlrm_shard(
         syn.dlrm_shard(i, n_rec, n_dense, n_sparse))
-    # NOTE: header words travel in packet 0 — the service must not mangle
-    # them; PreprocService only rewrites whole records, and we align the
-    # payload so the 3-word header occupies the first record slot.
+    # NOTE: PreprocService rewrites every whole record slot of a packet
+    # (word w below n_rec * rec_words as column w % rec_words) and passes
+    # only the tail through, so a shard's 3-word header must not ride in
+    # a record slot: the packets below carry the records alone.
     raw = syn.dlrm_shard(7, n_rec, n_dense, n_sparse)
     svc = PreprocService(n_dense=n_dense, n_sparse=n_sparse, modulus=modulus)
     chain = ServiceChain(on_path=[svc])

@@ -17,6 +17,7 @@ while modules are imported would give every test worker a different
 view of which tests exist.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -93,6 +94,34 @@ def test_preproc_compiles_for_v5e(one_chip):
     _compile(lambda r: preproc.preproc_pallas(r, n_dense, 100_000,
                                               interpret=False),
              one_chip, ((recs, n_dense + n_sparse), jnp.int32))
+
+
+CRITEO = (13, 27, 40_000_000)   # dense, categorical + label, id cap
+
+
+def test_preproc_packets_compiles_for_v5e(one_chip):
+    n_dense, n_sparse, modulus = CRITEO
+    _compile(lambda p: preproc.preproc_packets_pallas(
+        p, n_dense, n_dense + n_sparse, modulus, interpret=False),
+        one_chip, ((N_PKTS, MTU), jnp.uint8))
+
+
+def test_preproc_service_lowers_without_relayout(one_chip, monkeypatch):
+    """The service hands the packet rows to the kernel as they are: no
+    concatenate and no uint8 tensor with a minor dimension of 4 (the
+    byte-to-word relayout) around the call."""
+    from repro.core.services import PreprocService
+    monkeypatch.setattr(preproc, "interpret_mode", lambda interpret=None:
+                        False if interpret is None else interpret)
+    n_dense, n_sparse, modulus = CRITEO
+    svc = PreprocService(n_dense=n_dense, n_sparse=n_sparse,
+                         modulus=modulus, use_pallas=True)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in (((N_PKTS, MTU), jnp.uint8), ((N_PKTS,), jnp.int32))]
+    text = jax.jit(lambda p, n: svc(p, n)).lower(*args).as_text()
+    assert "tpu_custom_call" in text
+    assert "concatenate" not in text
+    assert not re.search(r"x4xui8>", text)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.int32])
